@@ -371,7 +371,11 @@ def main(argv=None) -> None:
 
     Ks = SMOKE_KS if args.smoke else FULL_KS
     Ps = SMOKE_PS if args.smoke else FULL_PS
-    backends = ["numpy", "jax", "pallas"]
+    # The pallas kernel runs only on a TPU backend (it raises elsewhere);
+    # the JSON's jax_backend says which arms a run could have.
+    backends = ["numpy", "jax"]
+    if scoring._jax_backend_name() == "tpu":
+        backends.append("pallas")
 
     print(f"== scoring core: plans-scored/sec (backends={backends}, "
           f"shards={args.shards}) ==")
@@ -397,4 +401,7 @@ def main(argv=None) -> None:
 
 
 if __name__ == "__main__":
+    from repro.launch.bootstrap import setup_compile_cache
+
+    setup_compile_cache()
     main()

@@ -64,4 +64,7 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro.launch.bootstrap import setup_compile_cache
+
+    setup_compile_cache()
     main()

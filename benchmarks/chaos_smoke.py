@@ -41,6 +41,15 @@ import tempfile
 
 
 def _serve(args, cwd):
+    # Each child needs the device: this parent may import repro (for the
+    # spec) but must never start a JAX backend, which would hold the chip.
+    if "jax" in sys.modules:
+        from jax._src import xla_bridge
+
+        if xla_bridge.backends_are_initialized():
+            raise SystemExit("chaos_smoke: the parent started a JAX backend; "
+                             "its repro.serve children could not reach "
+                             "the device")
     env = dict(os.environ)
     src = os.path.join(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))), "src")

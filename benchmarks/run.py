@@ -51,4 +51,7 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    from repro.launch.bootstrap import setup_compile_cache
+
+    setup_compile_cache()
     main()

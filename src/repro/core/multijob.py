@@ -40,6 +40,7 @@ import warnings
 from typing import Callable, Dict, List, Optional, Protocol, Sequence
 
 import numpy as np
+from jax.errors import JaxRuntimeError
 
 from repro.config.base import JobConfig
 from repro.core.cost import CostModel
@@ -467,6 +468,9 @@ class MultiJobEngine:
             # Bounded aggregation retries (SLO axis): 0 keeps the historical
             # fail-fast raise; N retries the dispatch, then records a
             # degraded round carrying the job's previous metrics forward.
+            # Errors of the JAX runtime itself (a compile failure, device
+            # memory exhausted) are never retried: a degraded round would
+            # hide a program that cannot run on its device.
             tries = 0
             while True:
                 try:
@@ -474,7 +478,8 @@ class MultiJobEngine:
                         job, f["survivors"], js.round_idx)
                     break
                 except Exception as e:
-                    if self.max_agg_retries <= 0:
+                    if (self.max_agg_retries <= 0
+                            or isinstance(e, JaxRuntimeError)):
                         raise
                     if tries >= self.max_agg_retries:
                         prev = next((r for r in reversed(self.records)

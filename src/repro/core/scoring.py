@@ -15,8 +15,8 @@ devices with Formula 2:
   stream, no materialized float intermediates; ~10-100x numpy on 10k+
   device pools even on CPU);
 - ``pallas`` — the tiled TPU kernel in ``repro.kernels.sched_score``
-  (sufficient-statistics reduction; falls back to the jax reference with a
-  logged warning off-TPU).
+  (sufficient-statistics reduction; raises off-TPU — the CPU tests run it
+  explicitly in interpret mode through ``score_plans_pallas_interpret``).
 
 ``backend="auto"`` (the default) picks numpy below a per-FORM element
 threshold (``AUTO_NUMPY_MAX_DENSE`` / ``AUTO_NUMPY_MAX_INDEX``) and jax
@@ -32,13 +32,11 @@ experiment layer wires ``ExperimentSpec.fleet.scoring_backend`` through
 from __future__ import annotations
 
 import functools
-import logging
 import threading
 from typing import Optional, Tuple
 
 import numpy as np
 
-logger = logging.getLogger(__name__)
 
 VALID_BACKENDS = ("auto", "numpy", "jax", "pallas")
 
@@ -61,7 +59,6 @@ AUTO_NUMPY_MAX = AUTO_NUMPY_MAX_DENSE
 MIN_SHARD_ELEMENTS = 1 << 12
 
 _state = threading.local()
-_warned_pallas_fallback = False
 
 
 def set_default_backend(backend: str) -> None:
@@ -93,15 +90,11 @@ def resolve_backend(backend: Optional[str], num_elements: int,
                     else "jax")
         cap = AUTO_NUMPY_MAX_INDEX if form == "index" else AUTO_NUMPY_MAX_DENSE
         return "numpy" if num_elements <= cap else "jax"
-    if b == "pallas" and not _pallas_available():
-        global _warned_pallas_fallback
-        if not _warned_pallas_fallback:
-            logger.warning(
-                "scoring backend 'pallas' requested but the default JAX "
-                "backend is %s (TPU required) — falling back to the jitted "
-                "jax reference", _jax_backend_name())
-            _warned_pallas_fallback = True
-        return "jax"
+    if b == "pallas" and _jax_backend_name() != "tpu":
+        raise RuntimeError(
+            "scoring backend 'pallas' needs a TPU, but the default JAX "
+            f"backend is {_jax_backend_name()!r}; use 'jax' or 'auto' here "
+            "(tests run the kernel through score_plans_pallas_interpret)")
     return b
 
 
@@ -109,13 +102,6 @@ def _jax_backend_name() -> str:
     import jax
 
     return jax.default_backend()
-
-
-def _pallas_available() -> bool:
-    try:
-        return _jax_backend_name() == "tpu"
-    except Exception:  # pragma: no cover - no jax runtime at all
-        return False
 
 
 # ---- jitted jax reference ----------------------------------------------
@@ -308,7 +294,7 @@ def score_plans(times: np.ndarray, counts: np.ndarray, plans: np.ndarray,
                  jnp.float32(alpha), jnp.float32(beta),
                  jnp.float32(time_scale), jnp.float32(fairness_scale))
         return np.asarray(out, dtype=np.float64)
-    # pallas (resolve_backend already verified TPU availability)
+    # pallas (resolve_backend already verified the TPU backend)
     stats = plan_stats_pallas(times, counts_c, plans)
     return _score_from_stats(stats, counts_c, alpha, beta,
                              time_scale, fairness_scale, delta_fairness)
@@ -354,7 +340,6 @@ def score_plan_indices(times: np.ndarray, counts: np.ndarray,
             c2 = float(np.sum(np.square(counts, dtype=np.float64)))
             f = (c2 + wsum) / K - ((c1 + S) / K) ** 2
         return alpha * t + beta * f / fairness_scale
-    # jax (pallas has no index-form kernel; the gather path is already tiny)
     import jax.numpy as jnp
 
     counts_c = counts.astype(np.float64) - float(np.mean(counts))
@@ -363,6 +348,15 @@ def score_plan_indices(times: np.ndarray, counts: np.ndarray,
 
         stats = shard.plan_stats_sharded(times, counts_c, idx, "index",
                                          num_shards)
+        return _score_from_stats(stats, counts_c, alpha, beta,
+                                 time_scale, fairness_scale, delta_fairness)
+    if b == "pallas":
+        # The kernel streams dense int8 tiles: scatter the rows to them.
+        # (``auto`` never picks this — the index gather is far cheaper.)
+        from repro.core.plans import indices_to_plans
+
+        stats = plan_stats_pallas(times, counts_c,
+                                  indices_to_plans(idx, K, dtype=np.int8))
         return _score_from_stats(stats, counts_c, alpha, beta,
                                  time_scale, fairness_scale, delta_fairness)
     fn = _jax_score_idx_fn(bool(delta_fairness))

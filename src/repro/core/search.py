@@ -60,24 +60,22 @@ logger = logging.getLogger(__name__)
 def _usable_search_shards(num_shards, rows: int, pairs: bool = False) -> int:
     """Shard count a fused searcher can actually use for ``rows`` parallel
     units (SA chains / GA population / BODS candidates): falls back to the
-    single lane when the process lacks devices, when ``rows`` does not
+    single lane when the CPU platform lacks devices, when ``rows`` does not
     split evenly, or (``pairs``) when the per-shard block would break the
     GA's consecutive-pair crossover. Falling back changes NOTHING but the
     partitioning — the single-lane program is the num_shards=1 special
-    case of the same math."""
+    case of the same math. On an accelerator, more shards than chips
+    raise (``shard.check_shard_capacity``)."""
+    from repro.core import shard
+
     n = int(num_shards or 1)
     if n <= 1:
         return 1
     reason = None
-    try:
-        from repro.core import shard
-
-        if n > shard.shard_capacity():
-            reason = (f"num_shards={n} exceeds jax.device_count(); "
-                      "launch via repro.launch.bootstrap to size the "
-                      "host platform")
-    except Exception:  # pragma: no cover - no jax runtime
-        reason = "no jax runtime"
+    if not shard.check_shard_capacity(n):
+        reason = (f"num_shards={n} exceeds jax.device_count(); "
+                  "launch via repro.launch.bootstrap to size the "
+                  "host platform")
     if reason is None and rows % n:
         reason = f"{rows} search rows do not split across {n} shards"
     if reason is None and pairs and (rows // n) % 2:
@@ -283,18 +281,17 @@ def _sa_fn(steps: int, chains: int, n_sel: int, delta_fairness: bool,
         return best_i, best_c
 
     if num_shards > 1:
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
         from repro.core.shard import fleet_mesh
 
-        chains_run = shard_map(
+        chains_run = jax.shard_map(
             chains_run, mesh=fleet_mesh(num_shards),
             in_specs=(P("fleet", None), P(None), P(None),
                       P(None, "fleet"), P(None, "fleet"), P(None, "fleet"),
                       P(), P(), P(), P(), P(), P()),
             out_specs=(P("fleet", None), P("fleet")),
-            check_rep=False)
+            check_vma=False)
 
     def run(*args):
         best_i, best_c = chains_run(*args)
@@ -332,9 +329,11 @@ def sa_search(rng: np.random.Generator, times: np.ndarray, counts: np.ndarray,
     if greedy_seed:
         init[0] = _greedy_indices(np.asarray(times), avail_idx, n_sel)
     pos, cand, u = _swap_noise(rng, avail_idx, steps, chains, n_sel)
+    shards = _usable_search_shards(num_shards, chains)
     fn = _sa_fn(int(steps), int(chains), int(n_sel), bool(delta_fairness),
-                _usable_search_shards(num_shards, chains))
-    with span("sa_search", chains=int(chains), steps=int(steps)):
+                shards)
+    with span("sa_search", chains=int(chains), steps=int(steps),
+              shards=shards):
         best_idx, _ = fn(jnp.asarray(init), jnp.asarray(times, jnp.float32),
                          jnp.asarray(_center(counts)), jnp.asarray(pos),
                          jnp.asarray(cand), jnp.asarray(u),
@@ -489,18 +488,17 @@ def _ga_fn(population: int, generations: int, n_sel: int,
         return (jnp.where(better, pop[i], best_i)[None],
                 jnp.where(better, cost[i], best_c)[None])
 
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as Psp
 
     from repro.core.shard import fleet_mesh
 
     rep = Psp()
-    sharded = shard_map(
+    sharded = jax.shard_map(
         run_shard, mesh=fleet_mesh(N),
         in_specs=(Psp("fleet", None), rep, rep, rep, rep, rep,
                   rep, rep, rep, rep, rep, rep, rep, rep),
         out_specs=(Psp("fleet", None), Psp("fleet")),
-        check_rep=False)
+        check_vma=False)
 
     def run(*args):
         best_i, best_c = sharded(*args)
@@ -537,9 +535,10 @@ def ga_search(rng: np.random.Generator, times: np.ndarray, counts: np.ndarray,
     cross_u = rng.random((G, half, n_sel)).astype(np.float32)
     mut_u = rng.random((G, P)).astype(np.float32)
     mut_pos, mut_cand, _ = _swap_noise(rng, avail_idx, G, P, n_sel)
-    fn = _ga_fn(int(P), int(G), int(n_sel), bool(delta_fairness),
-                _usable_search_shards(num_shards, P, pairs=True))
-    with span("ga_search", population=int(P), generations=int(G)):
+    shards = _usable_search_shards(num_shards, P, pairs=True)
+    fn = _ga_fn(int(P), int(G), int(n_sel), bool(delta_fairness), shards)
+    with span("ga_search", population=int(P), generations=int(G),
+              shards=shards):
         best_idx, _ = fn(jnp.asarray(init), jnp.asarray(times, jnp.float32),
                          jnp.asarray(_center(counts)), jnp.asarray(tourn[0]),
                          jnp.asarray(tourn[1]), jnp.asarray(cross_u),
@@ -772,7 +771,7 @@ def _bods_fn(num_candidates: int, n_mut: int, n_sel: int,
                 noise)
             ei = ei_from_posterior(mu_c, sigma, jnp.min(mu_c))
             choice = jnp.argmax(ei)
-            return cands[choice], cand_est[choice]
+            return cands[choice], cand_est[choice], ei[choice]
 
         return jax.jit(run)
 
@@ -794,17 +793,16 @@ def _bods_fn(num_candidates: int, n_mut: int, n_sel: int,
         c = jnp.argmax(ei)
         return cands[c][None], cand_est[c][None], ei[c][None], ids[c][None]
 
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as Psp
 
     from repro.core.shard import fleet_mesh
 
     rep = Psp()
-    sharded = shard_map(
+    sharded = jax.shard_map(
         run_shard, mesh=fleet_mesh(N), in_specs=(rep,) * 17,
         out_specs=(Psp("fleet", None), Psp("fleet"), Psp("fleet"),
                    Psp("fleet")),
-        check_rep=False)
+        check_vma=False)
 
     def run(*args):
         plans, ests, eis, gids = sharded(*args)
@@ -813,7 +811,7 @@ def _bods_fn(num_candidates: int, n_mut: int, n_sel: int,
         order = jnp.where(eis == jnp.max(eis), gids,
                           jnp.iinfo(jnp.int32).max)
         wi = jnp.argmin(order)
-        return plans[wi], ests[wi]
+        return plans[wi], ests[wi], eis[wi]
 
     return jax.jit(run)
 
@@ -870,13 +868,13 @@ def bods_acquire(rng: np.random.Generator, times: np.ndarray,
                                     n_mut)
     else:
         mutants = np.zeros((n_mut, avail.shape[0]), dtype=bool)
+    shards = _usable_search_shards(num_shards, num_candidates)
     fn = _bods_fn(int(num_candidates), int(n_mut), int(n_sel),
-                  bool(delta_fairness), bool(local_search),
-                  _usable_search_shards(num_shards, num_candidates))
+                  bool(delta_fairness), bool(local_search), shards)
     seed = jnp.uint32(int(rng.integers(0, 2**31 - 1)))
     with span("bods_acquire", candidates=int(num_candidates),
-              mutants=int(n_mut)):
-        plan, cand_est = fn(
+              mutants=int(n_mut), shards=shards):
+        plan, cand_est, ei = fn(
             seed, jnp.asarray(times, jnp.float32),
             jnp.asarray(_center(counts)),
             jnp.asarray(np.asarray(counts) == 0), jnp.asarray(avail),
@@ -887,4 +885,9 @@ def bods_acquire(rng: np.random.Generator, times: np.ndarray,
             jnp.float32(alpha), jnp.float32(beta), jnp.float32(time_scale),
             jnp.float32(fairness_scale), jnp.float32(gp_noise))
         out = np.asarray(plan), float(cand_est)
+    if not np.isfinite(float(ei)):
+        # argmax over a NaN posterior silently picks candidate 0.
+        raise FloatingPointError(
+            f"BODS acquisition: non-finite EI {float(ei)} for the chosen "
+            "candidate (GP posterior broke down)")
     return out

@@ -3,9 +3,10 @@
 The scoring core (``repro.core.scoring``) and the fused searchers
 (``repro.core.search``) are single-lane jit programs; they top out around
 K = 1e5 devices because every reduction walks the whole fleet axis on one
-device. This module shards the FLEET (K) axis across the host platform's
-devices (``XLA_FLAGS=--xla_force_host_platform_device_count=N`` — see
-``repro.launch.bootstrap``) with ``shard_map``:
+device. This module shards the FLEET (K) axis across the process's
+devices — the chips of a TPU host, or forced CPU host devices
+(``XLA_FLAGS=--xla_force_host_platform_device_count=N`` — see
+``repro.launch.bootstrap``) — with ``shard_map``:
 
 - **Scoring** (``plan_stats_sharded``) — each shard reduces its K/N block
   of the fleet to the per-plan sufficient statistics of Formula 2
@@ -30,11 +31,12 @@ Every sharded program has two executors with identical shard-local math:
   reshaped leading shard axis on ONE device.
 
 ``executor="auto"`` picks ``shard_map`` when the process has enough
-devices and falls back to emulation otherwise, so ``num_shards=8``
-produces the same numbers on a laptop (serially) and on an
-8-device host platform (in parallel). Tests exploit this: emulated
-parity runs in-process anywhere; a subprocess test with forced host
-devices pins shard_map-vs-emulated agreement.
+devices. On the CPU platform it emulates otherwise, so ``num_shards=8``
+produces the same numbers on a laptop (serially) and on an 8-device host
+platform (in parallel); on an accelerator, more shards than chips raise.
+Tests exploit this: emulated parity runs in-process anywhere; a
+subprocess test with forced host devices pins shard_map-vs-emulated
+agreement.
 """
 
 from __future__ import annotations
@@ -80,15 +82,27 @@ def shard_capacity() -> int:
     return int(jax.device_count())
 
 
+def check_shard_capacity(num_shards: int) -> bool:
+    """True when ``num_shards`` fit the process's devices. On an
+    accelerator, more shards than chips raise: emulating them serially on
+    one chip would hide a mis-sized launch. The CPU platform emulates."""
+    import jax
+
+    if num_shards <= shard_capacity():
+        return True
+    if jax.default_backend() != "cpu":
+        raise ValueError(
+            f"num_shards={num_shards} exceeds the {shard_capacity()} "
+            f"{jax.default_backend()} device(s) of this process")
+    return False
+
+
 def _resolve_executor(executor: str, num_shards: int) -> str:
     if executor not in VALID_EXECUTORS:
         raise ValueError(f"executor {executor!r} not in {VALID_EXECUTORS}")
     if executor != "auto":
         return executor
-    try:
-        return "shard_map" if num_shards <= shard_capacity() else "emulate"
-    except Exception:  # pragma: no cover - no jax runtime
-        return "emulate"
+    return "shard_map" if check_shard_capacity(num_shards) else "emulate"
 
 
 def shard_sizes(K: int, num_shards: int) -> Tuple[int, int]:
@@ -156,7 +170,6 @@ def _stats_fn(num_shards: int, form: str, executor: str):
 
     N = num_shards
     if executor == "shard_map":
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
         mesh = fleet_mesh(N)
@@ -164,7 +177,7 @@ def _stats_fn(num_shards: int, form: str, executor: str):
             def body(times_b, w_b, plans_b):
                 return _partial_stats_dense(times_b, w_b, plans_b)[None]
 
-            fn = shard_map(body, mesh=mesh,
+            fn = jax.shard_map(body, mesh=mesh,
                            in_specs=(P("fleet"), P("fleet"), P(None, "fleet")),
                            out_specs=P("fleet", None, None))
         else:
@@ -172,7 +185,7 @@ def _stats_fn(num_shards: int, form: str, executor: str):
                 lo = jax.lax.axis_index("fleet") * times_b.shape[0]
                 return _partial_stats_index(times_b, w_b, idx, lo)[None]
 
-            fn = shard_map(body, mesh=mesh,
+            fn = jax.shard_map(body, mesh=mesh,
                            in_specs=(P("fleet"), P("fleet"), P(None, None)),
                            out_specs=P("fleet", None, None))
         return jax.jit(fn)
@@ -295,7 +308,6 @@ def _noisy_topk_fn(num_shards: int, n_sel: int, executor: str, mode: str,
         return v, gi
 
     if executor == "shard_map":
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
         mesh = fleet_mesh(N)
@@ -307,7 +319,7 @@ def _noisy_topk_fn(num_shards: int, n_sel: int, executor: str, mode: str,
             return v[None], gi[None]
 
         mat_spec = P() if mode == "random" else P(None, "fleet")
-        inner = shard_map(
+        inner = jax.shard_map(
             sm_body, mesh=mesh,
             in_specs=(P(), P("fleet"), mat_spec),
             out_specs=(P("fleet", None, None), P("fleet", None, None)))

@@ -159,4 +159,7 @@ def main(argv=None) -> None:
 
 
 if __name__ == "__main__":
+    from repro.launch.bootstrap import setup_compile_cache
+
+    setup_compile_cache()
     main(sys.argv[1:])

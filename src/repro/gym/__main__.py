@@ -3,5 +3,7 @@
 import sys
 
 from repro.gym.cli import main
+from repro.launch.bootstrap import setup_compile_cache
 
+setup_compile_cache()
 main(sys.argv[1:])

@@ -38,7 +38,9 @@ STATS_LANES = 128  # output lane width (TPU tile); cols 0..2 carry the stats
 
 def _score_kernel(times_ref, w_ref, plans_ref, stats_ref):
     k_idx = pl.program_id(1)
-    p = plans_ref[...] != 0                       # (BP, BK) bool
+    # Widen before the compare: Mosaic cannot relayout an i1 mask built
+    # straight from an int8 tile, so the mask comes from an int32 tile.
+    p = plans_ref[...].astype(jnp.int32) != 0     # (BP, BK) bool
     t = times_ref[...].astype(jnp.float32)        # (1, BK)
     w = w_ref[...].astype(jnp.float32)            # (1, BK)
 

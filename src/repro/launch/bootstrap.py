@@ -1,16 +1,25 @@
-"""Host-platform bootstrap: size the jax CPU "fleet" BEFORE jax imports.
+"""Process bootstrap: the compile cache, and the jax CPU "fleet" size.
+
+``setup_compile_cache`` places JAX's persistent compilation cache; every
+entry point calls it before its first compile (see its docstring).
+
+``ensure_host_devices`` sizes the CPU host platform BEFORE jax starts.
 
 The fleet-sharding layer (``repro.core.shard``) partitions the K axis over
 ``jax.device_count()`` devices. On CPU that count is 1 unless the process
 was started with ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` —
-and XLA reads the flag at backend initialization, so setting it after
-``import jax`` (or after anything that imports jax) is a silent no-op.
+and XLA reads the flag once, when the backend starts (the first device
+query), so setting it after that is a silent no-op.
 Same story for tcmalloc: ``LD_PRELOAD`` only takes effect at process start.
 Hence this module's contract: import it and call ``ensure_host_devices``
 FIRST, before any jax import anywhere in the process; when the environment
 is missing it re-execs the interpreter once with the right env and the
 marker ``REPRO_LAUNCH_BOOTSTRAPPED=1`` (so a misconfigured child can never
 re-exec forever).
+
+On a TPU host none of that applies: the chips are the devices, and a
+re-exec would start a second process that needs the chip the first one
+holds. ``ensure_host_devices`` then only checks the chip count.
 
 Typical use, first lines of a benchmark / experiment entry point::
 
@@ -33,6 +42,11 @@ from typing import Dict, Optional
 # satisfy the request fails loudly instead of exec-looping.
 _MARKER = "REPRO_LAUNCH_BOOTSTRAPPED"
 
+_CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
+# The checkout root (this file is src/repro/launch/bootstrap.py).
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.dirname(os.path.abspath(__file__)))))
+
 _DEVICE_FLAG = "--xla_force_host_platform_device_count"
 
 # Common tcmalloc locations (Debian/Ubuntu multiarch, RHEL, conda).
@@ -43,6 +57,35 @@ _TCMALLOC_CANDIDATES = (
     "/usr/lib64/libtcmalloc.so.4",
     "/usr/lib/libtcmalloc.so.4",
 )
+
+
+def compile_cache_dir() -> Optional[str]:
+    """The directory ``setup_compile_cache`` gives JAX: None when
+    ``JAX_COMPILATION_CACHE_DIR`` is set (JAX reads that variable itself),
+    else the fixed ``.jax_cache/`` at the checkout root. The path is part
+    of every cache key, so it never depends on a pid, the time or a
+    temporary directory."""
+    if os.environ.get(_CACHE_ENV):
+        return None
+    return os.path.join(_CHECKOUT, ".jax_cache")
+
+
+def setup_compile_cache() -> Optional[str]:
+    """Turn on JAX's persistent compilation cache at ``compile_cache_dir``
+    (nothing is set when the environment already places it). Call before
+    the first compile; returns the directory set, or None."""
+    path = compile_cache_dir()
+    if path is not None:
+        import jax
+
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
+def _cpu_platform_forced() -> bool:
+    """``JAX_PLATFORMS`` names platforms and none of them is a TPU."""
+    plats = os.environ.get("JAX_PLATFORMS", "")
+    return bool(plats) and "tpu" not in plats.split(",")
 
 
 def find_tcmalloc() -> Optional[str]:
@@ -90,14 +133,16 @@ def _current_device_flag() -> Optional[int]:
 
 
 def ensure_host_devices(num_shards: int, tcmalloc: bool = True) -> bool:
-    """Make sure this process runs with >= ``num_shards`` host devices.
+    """Make sure this process runs with >= ``num_shards`` devices.
 
     Returns True when the environment already satisfies the request (also
-    covers real multi-device backends, and num_shards <= 1). Otherwise
-    re-execs the CURRENT interpreter with ``host_platform_env`` applied —
-    the call does not return in that case. Must run before jax is
-    imported; if jax is already in ``sys.modules`` with too few devices,
-    raises RuntimeError instead of silently mis-sharding.
+    covers real multi-device backends, and num_shards <= 1). Unless
+    ``JAX_PLATFORMS`` pins a non-TPU platform, the backend starts here:
+    on a TPU the chips must suffice (RuntimeError otherwise) and nothing
+    is re-exec'd. On the CPU platform this re-execs the CURRENT interpreter
+    with ``host_platform_env`` applied — the call does not return in that
+    case; if the CPU backend already started with too few devices, raises
+    RuntimeError instead of silently mis-sharding.
     """
     n = int(num_shards)
     if n <= 1:
@@ -105,16 +150,24 @@ def ensure_host_devices(num_shards: int, tcmalloc: bool = True) -> bool:
     flag = _current_device_flag()
     if flag is not None and flag >= n:
         return True
-    if "jax" in sys.modules:
+    imported_before = "jax" in sys.modules
+    if imported_before or not _cpu_platform_forced():
         import jax
 
         if jax.device_count() >= n:
             return True
-        raise RuntimeError(
-            f"need {n} devices but jax initialized with "
-            f"{jax.device_count()}; call ensure_host_devices() before "
-            "importing jax (or launch with "
-            f"XLA_FLAGS={_DEVICE_FLAG}={n})")
+        if jax.default_backend() != "cpu":
+            raise RuntimeError(
+                f"need {n} devices but this {jax.default_backend()} host "
+                f"has {jax.device_count()}")
+        if imported_before:
+            raise RuntimeError(
+                f"need {n} devices but jax initialized with "
+                f"{jax.device_count()}; call ensure_host_devices() before "
+                "importing jax (or launch with "
+                f"XLA_FLAGS={_DEVICE_FLAG}={n})")
+        # Only the CPU backend started here, and it holds no chip: the
+        # re-exec below restarts it with the forced device count.
     if os.environ.get(_MARKER):
         raise RuntimeError(
             f"bootstrap re-exec did not produce {n} host devices "

@@ -1,13 +1,9 @@
-import os
-os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") +
-                           " --xla_force_host_platform_device_count=512").strip()
-
 """Multi-pod dry-run: lower + compile every (arch × shape × mesh) cell.
 
-MUST be run as a module (``python -m repro.launch.dryrun``): the XLA flag
-above executes before any jax import so 512 host devices exist for
-``jax.make_mesh``. Never set that flag globally — tests and benches see 1
-device.
+Run it as a module (``python -m repro.launch.dryrun``): its ``__main__``
+block forces 512 CPU host devices before the backend starts, so
+``jax.make_mesh`` finds the production mesh. Importing the module sets
+nothing — tests and benches keep their own devices.
 
 Per cell it jit-lowers the step with explicit in/out shardings resolved from
 the logical-axis rules, compiles, and records memory_analysis(),
@@ -22,6 +18,7 @@ Usage:
 
 import argparse
 import json
+import os
 import time
 import traceback
 from typing import Any, Dict, Optional
@@ -413,4 +410,8 @@ def main():
 
 
 if __name__ == "__main__":
+    # Read when the backend starts (first device query), not at import.
+    os.environ["XLA_FLAGS"] = (
+        os.environ.get("XLA_FLAGS", "")
+        + " --xla_force_host_platform_device_count=512").strip()
     main()

@@ -77,15 +77,11 @@ def axis_rules(rules: Optional[Dict[str, Tuple[str, ...]]] = None, **overrides):
 
 
 def _active_mesh() -> Optional[Mesh]:
-    mesh = jax.sharding.get_abstract_mesh() if hasattr(jax.sharding, "get_abstract_mesh") else None
-    try:
-        from jax._src import mesh as mesh_lib
-        env_mesh = mesh_lib.thread_resources.env.physical_mesh
-        if env_mesh is not None and not env_mesh.empty:
-            return env_mesh
-    except Exception:
-        pass
-    return None
+    # The mesh of an enclosing ``with mesh:`` block, if any.
+    from jax._src import mesh as mesh_lib
+
+    env_mesh = mesh_lib.thread_resources.env.physical_mesh
+    return None if env_mesh.empty else env_mesh
 
 
 def resolve_spec(shape: Sequence[int], logical_axes: Sequence[Optional[str]],

@@ -182,6 +182,20 @@ class TestSearchShardFallback:
         assert f(4, 32, pairs=True) == 4 or f(4, 32, pairs=True) == 1
         assert f(4, 12, pairs=True) == 1  # 12/4 = 3 rows/shard, odd pairs
 
+    def test_too_many_shards_raise_on_an_accelerator(self, monkeypatch):
+        """The CPU platform emulates missing shards; on a TPU the same
+        request raises in the scoring executor and in the searchers."""
+        import jax
+
+        n = shard.shard_capacity() + 1
+        assert shard._resolve_executor("auto", n) == "emulate"
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        with pytest.raises(ValueError, match="tpu device"):
+            shard._resolve_executor("auto", n)
+        with pytest.raises(ValueError, match="tpu device"):
+            search._usable_search_shards(n, 32 * n)
+        assert shard._resolve_executor("auto", 1) == "shard_map"
+
     def _scenario(self, K=96, seed=0):
         pool = DevicePool.heterogeneous(K, 2, seed=seed)
         rng = np.random.default_rng(seed + 7)
@@ -346,6 +360,51 @@ class TestBootstrap:
                                                "before importing"):
             bootstrap.ensure_host_devices(need)
 
+    def test_tpu_host_never_reexecs(self, monkeypatch):
+        """On a TPU the chips are the devices: too few raise, and the
+        process is never re-exec'd with forced CPU devices (a second
+        process could not reach the chip the first one holds)."""
+        from repro.launch import bootstrap
+
+        import jax
+
+        def execve(*a):
+            raise AssertionError("re-exec on a TPU host")
+
+        need = jax.device_count() + 1
+        monkeypatch.delenv("XLA_FLAGS", raising=False)
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        monkeypatch.setattr(os, "execve", execve)
+        with pytest.raises(RuntimeError, match="tpu host"):
+            bootstrap.ensure_host_devices(need)
+
+    def test_compile_cache_env_is_left_alone(self, monkeypatch):
+        from repro.launch import bootstrap
+
+        import jax
+
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/cache")
+        before = jax.config.jax_compilation_cache_dir
+        assert bootstrap.compile_cache_dir() is None
+        assert bootstrap.setup_compile_cache() is None
+        assert jax.config.jax_compilation_cache_dir == before
+
+    def test_compile_cache_defaults_to_fixed_checkout_dir(self, monkeypatch):
+        from repro.launch import bootstrap
+
+        import jax
+
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        path = bootstrap.compile_cache_dir()
+        assert path == os.path.join(REPO, ".jax_cache")
+        assert bootstrap.compile_cache_dir() == path  # no pid, time, tempdir
+        before = jax.config.jax_compilation_cache_dir
+        try:
+            assert bootstrap.setup_compile_cache() == path
+            assert jax.config.jax_compilation_cache_dir == path
+        finally:
+            jax.config.update("jax_compilation_cache_dir", before)
+
 
 # ---- real shard_map vs single lane (8 forced host devices) ---------------
 
@@ -440,6 +499,7 @@ def test_shard_map_parity_eight_devices():
     env.update({
         "PYTHONPATH": os.path.join(REPO, "src"),
         "XLA_FLAGS": "--xla_force_host_platform_device_count=8",
+        "JAX_PLATFORMS": "cpu",
     })
     out = subprocess.run([sys.executable, "-c", _SUBPROC],
                          capture_output=True, text=True, env=env,

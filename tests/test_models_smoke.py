@@ -140,7 +140,9 @@ def test_cnn_conv_impls_agree(arch):
         cnn_zoo.set_conv_impl("gemm")
     np.testing.assert_allclose(np.asarray(out_g), np.asarray(out_l),
                                atol=1e-4, rtol=1e-4)
-    assert abs(float(loss_g) - float(loss_l)) < 1e-5
+    # Relative: one float32 ulp of a ~80 loss (resnet18) is already 7.6e-6,
+    # so a fixed 1e-5 bound fails on two ulps of summation-order noise.
+    assert abs(float(loss_g) - float(loss_l)) <= 1e-6 * abs(float(loss_l))
     has_pool = any(layer[0] == "convp" for layer in cfg.cnn_spec)
     if not has_pool:
         for a, b in zip(jax.tree_util.tree_leaves(grad_g),

@@ -337,6 +337,26 @@ def test_bounded_agg_retries_record_degraded_round():
     assert calls["n"] == len(records) + 1
 
 
+def test_agg_retry_never_swallows_jax_runtime_errors():
+    """A compile failure or device OOM surfaces as ``JaxRuntimeError``: it
+    propagates on the first attempt even with a retry budget, instead of
+    becoming a degraded round."""
+    from jax.errors import JaxRuntimeError
+
+    spec = small_quickstart(max_rounds=2).replace(slo={"max_agg_retries": 1})
+    ex = spec.build()
+    calls = {"n": 0}
+
+    def oom(job_id, device_ids, round_idx):
+        calls["n"] += 1
+        raise JaxRuntimeError("RESOURCE_EXHAUSTED: out of device memory")
+
+    ex.engine.runtime.run_round = oom
+    with pytest.raises(JaxRuntimeError, match="RESOURCE_EXHAUSTED"):
+        ex.run()
+    assert calls["n"] == 1
+
+
 def test_agg_failure_without_retry_budget_still_raises():
     spec = small_quickstart(max_rounds=2)
     ex = spec.build()

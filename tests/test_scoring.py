@@ -174,9 +174,15 @@ def test_cost_model_batch_backends_agree():
     cm = CostModel(pool, alpha=4.0, beta=0.25)
     cm.calibrate([5.0, 5.0], n_sel=6)
     ref = cm.cost_batch(t, counts, plans, backend="numpy")
-    for backend in ("jax", "auto", "pallas"):  # pallas falls back off-TPU
+    for backend in ("jax", "auto"):
         np.testing.assert_allclose(
             cm.cost_batch(t, counts, plans, backend=backend), ref, **TOL)
+    # The pallas kernel itself, in interpret mode (its TPU backend raises
+    # off-chip).
+    np.testing.assert_allclose(scoring.score_plans_pallas_interpret(
+        t, counts, plans, alpha=cm.alpha, beta=cm.beta,
+        time_scale=cm.time_scale, fairness_scale=cm.fairness_scale,
+        delta_fairness=cm.delta_fairness), ref, **TOL)
 
 
 def test_round_time_and_fairness_batch_parity():
@@ -205,17 +211,20 @@ def test_auto_dispatch_and_default_backend():
         scoring.resolve_backend("cuda", 1)
 
 
-def test_pallas_requires_tpu_else_falls_back(caplog):
-    import logging
+def test_pallas_requires_tpu_else_falls_back():
+    """Off TPU an explicit ``pallas`` backend raises, naming the backend it
+    found, instead of quietly scoring with the jax reference."""
+    import jax
 
-    scoring._warned_pallas_fallback = False
-    with caplog.at_level(logging.WARNING, logger="repro.core.scoring"):
-        b = scoring.resolve_backend("pallas", 10**6)
-    if scoring._pallas_available():  # pragma: no cover - TPU CI only
-        assert b == "pallas"
-    else:
-        assert b == "jax"
-        assert any("falling back" in r.message for r in caplog.records)
+    if jax.default_backend() == "tpu":  # pragma: no cover - chip only
+        assert scoring.resolve_backend("pallas", 10**6) == "pallas"
+        return
+    with pytest.raises(RuntimeError, match=jax.default_backend()):
+        scoring.resolve_backend("pallas", 10**6)
+    rng = np.random.default_rng(0)
+    times, counts, plans = make_problem(rng, 32, 4)
+    with pytest.raises(RuntimeError, match="needs a TPU"):
+        scoring.score_plans(times, counts, plans, backend="pallas")
 
 
 def test_gumbel_topk_biases_toward_high_logits():

@@ -1,5 +1,6 @@
 """Sharding-rule tests: divisibility fallback, axis dedup, multi-device lowering."""
 
+import os
 import subprocess
 import sys
 
@@ -70,6 +71,8 @@ from repro.launch.dryrun import lower_cell  # noqa: F401  (imports set up helper
 from repro.config import SHAPES
 from repro.config.base import ShapeConfig
 from repro.configs.qwen3_1p7b import reduced
+from repro.config.base import MeshConfig
+from repro.launch.mesh import make_mesh
 from repro.launch.sharding import tree_shardings
 from repro.launch.steps import batch_axes, input_specs, make_train_step, opt_state_axes
 from repro.config.base import TrainConfig, OptimizerConfig
@@ -77,7 +80,7 @@ from repro.models.layers import abstract_init
 from repro.models.transformer import lm_init
 
 cfg = reduced()
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh(MeshConfig(shape=(2, 4), axes=("data", "model")))
 shape = ShapeConfig("t", seq_len=32, global_batch=8, mode="train")
 with abstract_init():
     ps, pa = lm_init(cfg, 0)
@@ -97,10 +100,16 @@ with mesh:
 """
 
 
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
 def test_multidevice_train_step_compiles():
-    """8 virtual devices in a subprocess (XLA flag must precede jax import)."""
+    """8 virtual CPU devices in a subprocess (the XLA flag must be set
+    before the child's backend starts; the child never touches a chip)."""
+    env = dict(os.environ)
+    env.update({"PYTHONPATH": os.path.join(REPO, "src"),
+                "JAX_PLATFORMS": "cpu"})
     out = subprocess.run(
         [sys.executable, "-c", SUBPROC], capture_output=True, text=True,
-        env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
-             "HOME": "/root"}, cwd="/root/repo", timeout=600)
+        env=env, cwd=REPO, timeout=600)
     assert "COMPILED_OK True" in out.stdout, out.stderr[-2000:]
