@@ -321,138 +321,134 @@ class MultiJobEngine:
                 plan = self.scheduler.schedule(ctx)
                 rung = decision_ms = None
                 gov_est = getattr(self.scheduler, "last_estimated_cost", None)
-        dispatch_span = span("dispatch", job=job, round=js.round_idx)
-        dispatch_span.__enter__()
-        fe = self.fault_engine
-        # Realized time includes any remaining busy time (release_horizon > 0).
-        # Preallocated buffers: valid until this launch returns (nothing
-        # below stores a view of them).
-        times = self.pool.sample_times_into(
-            job, js.config.local_epochs, self._times_buf)
-        if fe is not None:
-            # Straggler slowdown multiplies COMPUTE time, not queueing wait.
-            slow = fe.straggler_multipliers(job, js.round_idx)
-            if slow is not None:
-                times *= slow
-        np.subtract(self.pool.busy_until, now, out=self._wait_buf)
-        np.maximum(self._wait_buf, 0.0, out=self._wait_buf)
-        times += self._wait_buf
-        sel_ids = np.flatnonzero(plan)
+        with span("dispatch", job=job, round=js.round_idx):
+            fe = self.fault_engine
+            # Realized time includes any remaining busy time (release_horizon > 0).
+            # Preallocated buffers: valid until this launch returns (nothing
+            # below stores a view of them).
+            times = self.pool.sample_times_into(
+                job, js.config.local_epochs, self._times_buf)
+            if fe is not None:
+                # Straggler slowdown multiplies COMPUTE time, not queueing wait.
+                slow = fe.straggler_multipliers(job, js.round_idx)
+                if slow is not None:
+                    times *= slow
+            np.subtract(self.pool.busy_until, now, out=self._wait_buf)
+            np.maximum(self._wait_buf, 0.0, out=self._wait_buf)
+            times += self._wait_buf
+            sel_ids = np.flatnonzero(plan)
 
-        # Straggler mitigation: with over-provisioning the round ends when the
-        # n_sel fastest of the scheduled set are done; the tail is dropped.
-        sel_times = times[sel_ids]
-        if len(sel_ids) > self.n_sel:
-            keep = sel_ids[np.argsort(sel_times)[: self.n_sel]]
-            dropped_straggler = np.setdiff1d(sel_ids, keep)
-        else:
-            keep, dropped_straggler = sel_ids, _EMPTY_IDS
+            # Straggler mitigation: with over-provisioning the round ends when the
+            # n_sel fastest of the scheduled set are done; the tail is dropped.
+            sel_times = times[sel_ids]
+            if len(sel_ids) > self.n_sel:
+                keep = sel_ids[np.argsort(sel_times)[: self.n_sel]]
+                dropped_straggler = np.setdiff1d(sel_ids, keep)
+            else:
+                keep, dropped_straggler = sel_ids, _EMPTY_IDS
 
-        # Fault injection: replayable keyed draws (transient dropouts,
-        # permanent crashes, correlated domain outages).
-        degraded = False
-        if fe is not None:
-            transient_m, crash_m, domain_m = fe.failure_masks(job, js.round_idx)
-            fail_mask = (transient_m | crash_m | domain_m)[keep]
-        else:
-            fail_mask = np.zeros(len(keep), dtype=bool)
-        failed = keep[fail_mask]
-        survivors = keep[~fail_mask]
-        if survivors.size == 0 and keep.size:
-            # Pathological: everyone failed. Keep the FASTEST reporter (its
-            # partial upload is the best single-device aggregate available)
-            # and mark the round degraded so summary() can surface it.
-            fastest = keep[np.argmin(times[keep])]
-            survivors = np.array([fastest])
-            failed = keep[keep != fastest]
-            degraded = True
-
-        # FedCS-style deadline: partial aggregation over on-time survivors.
-        # Late survivors still finish their local work (their devices stay
-        # busy until their own end time) but are cut from the cohort; they
-        # are NOT failures, so no quarantine strikes.
-        deadline_dropped = _EMPTY_IDS
-        if fe is not None and fe.spec.round_deadline is not None:
-            on_time = survivors[times[survivors] <= fe.spec.round_deadline]
-            if on_time.size == 0:
-                on_time = survivors[[np.argmin(times[survivors])]]
+            # Fault injection: replayable keyed draws (transient dropouts,
+            # permanent crashes, correlated domain outages).
+            degraded = False
+            if fe is not None:
+                transient_m, crash_m, domain_m = fe.failure_masks(job, js.round_idx)
+                fail_mask = (transient_m | crash_m | domain_m)[keep]
+            else:
+                fail_mask = np.zeros(len(keep), dtype=bool)
+            failed = keep[fail_mask]
+            survivors = keep[~fail_mask]
+            if survivors.size == 0 and keep.size:
+                # Pathological: everyone failed. Keep the FASTEST reporter (its
+                # partial upload is the best single-device aggregate available)
+                # and mark the round degraded so summary() can surface it.
+                fastest = keep[np.argmin(times[keep])]
+                survivors = np.array([fastest])
+                failed = keep[keep != fastest]
                 degraded = True
-            deadline_dropped = np.setdiff1d(survivors, on_time)
-            survivors = on_time
 
-        round_time = float(times[survivors].max())
-        t_end = now + round_time
-        # Devices are busy until THEIR OWN finish time (then free for other jobs).
-        per_dev_busy = self._busy_buf  # only masked entries are read by occupy
-        per_dev_busy[sel_ids] = now + times[sel_ids]
-        if fe is not None:
-            # Transient failures escalate (exponential-backoff quarantine,
-            # reset on success); domain outages park for the outage duration;
-            # crashes are permanent.
-            transient_ids = failed[transient_m[failed]]
-            domain_ids = failed[domain_m[failed] & ~crash_m[failed]]
-            crash_ids = failed[crash_m[failed]]
-            per_dev_busy[transient_ids] = (
-                t_end + fe.quarantine_durations(transient_ids))
-            per_dev_busy[domain_ids] = t_end + fe.spec.domain_outage_duration
-            per_dev_busy[crash_ids] = np.inf
-            fe.record_success(survivors)
-        elif failed.size:
-            per_dev_busy[failed] = t_end + self.failure_cooldown
-        busy_mask = self._mask_buf
-        busy_mask[:] = False
-        busy_mask[sel_ids] = True
-        self.pool.occupy(busy_mask, per_dev_busy)
-
-        # Corrupted uploads: a robust runtime injects + rejects them inside
-        # its own aggregation (``handles_corruption``); otherwise the engine
-        # oracle-discards them from the aggregation cohort. Either way they
-        # are excluded from the fairness counts (their update never landed).
-        corrupt_ids = (fe.corrupt_mask(job, js.round_idx, survivors)
-                       if fe is not None else None)
-        if corrupt_ids is not None and corrupt_ids.any():
-            corrupt_ids = survivors[corrupt_ids]
-            counted = np.setdiff1d(survivors, corrupt_ids)
-            if not getattr(self.runtime, "handles_corruption", False):
-                if counted.size == 0:
-                    # Every on-time update is corrupt and nothing can screen
-                    # them: aggregate the fastest anyway (degraded round).
-                    counted = survivors[[np.argmin(times[survivors])]]
+            # FedCS-style deadline: partial aggregation over on-time survivors.
+            # Late survivors still finish their local work (their devices stay
+            # busy until their own end time) but are cut from the cohort; they
+            # are NOT failures, so no quarantine strikes.
+            deadline_dropped = _EMPTY_IDS
+            if fe is not None and fe.spec.round_deadline is not None:
+                on_time = survivors[times[survivors] <= fe.spec.round_deadline]
+                if on_time.size == 0:
+                    on_time = survivors[[np.argmin(times[survivors])]]
                     degraded = True
-                survivors = counted
-        else:
-            corrupt_ids = _EMPTY_IDS
-            counted = survivors
+                deadline_dropped = np.setdiff1d(survivors, on_time)
+                survivors = on_time
 
-        cm = self.cost_model
-        fairness = cm.fairness(self.counts[job], plan)  # paper Formula 5 (absolute, recorded)
-        dfair = fairness - cm.fairness(self.counts[job]) if cm.delta_fairness else fairness
-        # Realized cost (scheduler feedback): realized straggler time + fairness.
-        cost = float(cm.alpha * round_time / cm.time_scale
-                     + cm.beta * dfair / cm.fairness_scale)
+            round_time = float(times[survivors].max())
+            t_end = now + round_time
+            # Devices are busy until THEIR OWN finish time (then free for other jobs).
+            per_dev_busy = self._busy_buf  # only masked entries are read by occupy
+            per_dev_busy[sel_ids] = now + times[sel_ids]
+            if fe is not None:
+                # Transient failures escalate (exponential-backoff quarantine,
+                # reset on success); domain outages park for the outage duration;
+                # crashes are permanent.
+                transient_ids = failed[transient_m[failed]]
+                domain_ids = failed[domain_m[failed] & ~crash_m[failed]]
+                crash_ids = failed[crash_m[failed]]
+                per_dev_busy[transient_ids] = (
+                    t_end + fe.quarantine_durations(transient_ids))
+                per_dev_busy[domain_ids] = t_end + fe.spec.domain_outage_duration
+                per_dev_busy[crash_ids] = np.inf
+                fe.record_success(survivors)
+            elif failed.size:
+                per_dev_busy[failed] = t_end + self.failure_cooldown
+            busy_mask = self._mask_buf
+            busy_mask[:] = False
+            busy_mask[sel_ids] = True
+            self.pool.occupy(busy_mask, per_dev_busy)
 
-        # Announce the realized cohort to batching runtimes at LAUNCH time:
-        # training is a pure function of (params, survivors), so a fused
-        # runtime can execute it any time before the finish event and batch
-        # every concurrently in-flight job into one dispatch.
-        begin = getattr(self.runtime, "begin_round", None)
-        if begin is not None:
-            begin(job, survivors, js.round_idx)
+            # Corrupted uploads: a robust runtime injects + rejects them inside
+            # its own aggregation (``handles_corruption``); otherwise the engine
+            # oracle-discards them from the aggregation cohort. Either way they
+            # are excluded from the fairness counts (their update never landed).
+            corrupt_ids = (fe.corrupt_mask(job, js.round_idx, survivors)
+                           if fe is not None else None)
+            if corrupt_ids is not None and corrupt_ids.any():
+                corrupt_ids = survivors[corrupt_ids]
+                counted = np.setdiff1d(survivors, corrupt_ids)
+                if not getattr(self.runtime, "handles_corruption", False):
+                    if counted.size == 0:
+                        # Every on-time update is corrupt and nothing can screen
+                        # them: aggregate the fastest anyway (degraded round).
+                        counted = survivors[[np.argmin(times[survivors])]]
+                        degraded = True
+                    survivors = counted
+            else:
+                corrupt_ids = _EMPTY_IDS
+                counted = survivors
 
-        self._in_flight[job] = dict(
-            plan=plan, survivors=survivors, counted=counted, failed=failed,
-            dropped=np.concatenate(
-                [dropped_straggler, failed, deadline_dropped]),
-            corrupt=corrupt_ids, degraded=degraded,
-            t_start=now, cost=cost, fairness=fairness, round_time=round_time,
-            est_cost=gov_est, rung=rung, decision_ms=decision_ms,
-            ctx=ctx,
-        )
-        heapq.heappush(self._heap, (float(t_end), self._seq, "finish", job))
-        self._seq += 1
-        # Close the dispatch span opened after the scheduling decision (the
-        # span is bookkeeping only: an exception above just drops the event).
-        dispatch_span.__exit__()
+            cm = self.cost_model
+            fairness = cm.fairness(self.counts[job], plan)  # paper Formula 5 (absolute, recorded)
+            dfair = fairness - cm.fairness(self.counts[job]) if cm.delta_fairness else fairness
+            # Realized cost (scheduler feedback): realized straggler time + fairness.
+            cost = float(cm.alpha * round_time / cm.time_scale
+                         + cm.beta * dfair / cm.fairness_scale)
+
+            # Announce the realized cohort to batching runtimes at LAUNCH time:
+            # training is a pure function of (params, survivors), so a fused
+            # runtime can execute it any time before the finish event and batch
+            # every concurrently in-flight job into one dispatch.
+            begin = getattr(self.runtime, "begin_round", None)
+            if begin is not None:
+                begin(job, survivors, js.round_idx)
+
+            self._in_flight[job] = dict(
+                plan=plan, survivors=survivors, counted=counted, failed=failed,
+                dropped=np.concatenate(
+                    [dropped_straggler, failed, deadline_dropped]),
+                corrupt=corrupt_ids, degraded=degraded,
+                t_start=now, cost=cost, fairness=fairness, round_time=round_time,
+                est_cost=gov_est, rung=rung, decision_ms=decision_ms,
+                ctx=ctx,
+            )
+            heapq.heappush(self._heap, (float(t_end), self._seq, "finish", job))
+            self._seq += 1
         if self.events is not None:
             self.events.publish("round_begin", dict(
                 job=job, round_idx=js.round_idx, t_start=now,

@@ -52,6 +52,7 @@ from typing import Optional, Tuple
 import numpy as np
 
 from repro.core.plans import plan_from_indices
+from repro.monitoring.trace import enabled as trace_enabled
 from repro.monitoring.trace import span
 
 logger = logging.getLogger(__name__)
@@ -293,12 +294,13 @@ def _sa_fn(steps: int, chains: int, n_sel: int, delta_fairness: bool,
             out_specs=(P("fleet", None), P("fleet")),
             check_vma=False)
 
-    def run(*args):
+    # Named for the searcher, so the device trace reads ``jit_sa_search``.
+    def sa_search(*args):
         best_i, best_c = chains_run(*args)
         ci = jnp.argmin(best_c)
         return best_i[ci], best_c[ci]
 
-    return jax.jit(run)
+    return jax.jit(sa_search)
 
 
 def sa_search(rng: np.random.Generator, times: np.ndarray, counts: np.ndarray,
@@ -407,9 +409,11 @@ def _ga_fn(population: int, generations: int, n_sel: int,
     N = num_shards
     Pb = P // N  # rows this shard owns (P itself when unsharded)
 
-    def run_single(init_idx, times, counts_c, tourn_a, tourn_b, cross_u,
-                   mut_u, mut_pos, mut_cand, alpha, beta, ts, fs,
-                   mutation_rate):
+    # Both executors are named for the searcher, so the device trace reads
+    # ``jit_ga_search``.
+    def ga_search(init_idx, times, counts_c, tourn_a, tourn_b, cross_u,
+                  mut_u, mut_pos, mut_cand, alpha, beta, ts, fs,
+                  mutation_rate):
         def body(carry, xs):
             pop, best_i, best_c = carry
             ta, tb, cu, mu, mpos, mcand = xs
@@ -437,7 +441,7 @@ def _ga_fn(population: int, generations: int, n_sel: int,
                 jnp.where(better, cost[i], best_c))
 
     if N == 1:
-        return jax.jit(run_single)
+        return jax.jit(ga_search)
 
     # Data-parallel sharded executor: each shard scores and breeds its own
     # Pb-row block, with one tiled ``all_gather`` of (population, cost) per
@@ -500,12 +504,12 @@ def _ga_fn(population: int, generations: int, n_sel: int,
         out_specs=(Psp("fleet", None), Psp("fleet")),
         check_vma=False)
 
-    def run(*args):
+    def ga_search(*args):
         best_i, best_c = sharded(*args)
         # Every shard returns the same global best; row 0 is canonical.
         return best_i[0], best_c[0]
 
-    return jax.jit(run)
+    return jax.jit(ga_search)
 
 
 def ga_search(rng: np.random.Generator, times: np.ndarray, counts: np.ndarray,
@@ -760,10 +764,12 @@ def _bods_fn(num_candidates: int, n_mut: int, n_sel: int,
         mu_c, sigma = gp_posterior(chol, w, m, F, feats, cand_est * inv_sd)
         return cands, cand_est, mu_c, sigma
 
+    # Both executors are named for the searcher, so the device trace reads
+    # ``jit_bods_acquire``.
     if N == 1:
-        def run(seed, times, counts_c, counts_zero, avail, mu, mutants,
-                use_base, F, resid, valid, inv_sd, alpha, beta, ts, fs,
-                noise):
+        def bods_acquire(seed, times, counts_c, counts_zero, avail, mu,
+                         mutants, use_base, F, resid, valid, inv_sd, alpha,
+                         beta, ts, fs, noise):
             ids = jnp.arange(P, dtype=jnp.int32)
             cands, cand_est, mu_c, sigma = block(
                 seed, ids, times, counts_c, counts_zero, avail, mu, mutants,
@@ -773,7 +779,7 @@ def _bods_fn(num_candidates: int, n_mut: int, n_sel: int,
             choice = jnp.argmax(ei)
             return cands[choice], cand_est[choice], ei[choice]
 
-        return jax.jit(run)
+        return jax.jit(bods_acquire)
 
     # Candidate-axis sharding: each shard generates/featurizes/scores its
     # own Pb candidates (the per-candidate PRNG keeps the candidate SET
@@ -804,7 +810,7 @@ def _bods_fn(num_candidates: int, n_mut: int, n_sel: int,
                    Psp("fleet")),
         check_vma=False)
 
-    def run(*args):
+    def bods_acquire(*args):
         plans, ests, eis, gids = sharded(*args)
         # Max EI wins; ties break to the LOWEST global candidate id,
         # matching the single lane's first-argmax semantics.
@@ -813,7 +819,7 @@ def _bods_fn(num_candidates: int, n_mut: int, n_sel: int,
         wi = jnp.argmin(order)
         return plans[wi], ests[wi], eis[wi]
 
-    return jax.jit(run)
+    return jax.jit(bods_acquire)
 
 
 def _mutate_plan_host(rng: np.random.Generator, base: np.ndarray,
@@ -854,40 +860,63 @@ def bods_acquire(rng: np.random.Generator, times: np.ndarray,
     K-wide draw in this module), so with ``num_shards`` > 1 the candidate
     axis partitions across host platform devices without changing the
     candidate set.
+
+    Traced, the decision splits into ``bods_prepare`` (host work before the
+    call) and, inside ``bods_acquire``, ``bods_stage`` (host-to-device
+    transfers), ``bods_launch`` (the dispatch), ``bods_wait`` (the device
+    work; only when tracing, since the read-back blocks at the same point
+    anyway) and ``bods_readback`` (the plan and its estimate; the EI check
+    reads once more after the span).
     """
+    import jax
     import jax.numpy as jnp
 
-    avail = np.asarray(available, dtype=bool)
-    if avail_idx is None:
-        avail_idx = np.flatnonzero(avail)
-    _check_avail(avail_idx, n_sel)
-    sd = float(y[valid > 0].std()) + 1e-6 if valid.sum() else 1.0
+    tracing = trace_enabled()
     use_base = base_plan is not None and local_search
-    if use_base:
-        mutants = _mutate_plan_host(rng, np.asarray(base_plan, dtype=bool),
-                                    n_mut)
-    else:
-        mutants = np.zeros((n_mut, avail.shape[0]), dtype=bool)
-    shards = _usable_search_shards(num_shards, num_candidates)
-    fn = _bods_fn(int(num_candidates), int(n_mut), int(n_sel),
-                  bool(delta_fairness), bool(local_search), shards)
-    seed = jnp.uint32(int(rng.integers(0, 2**31 - 1)))
+    with span("bods_prepare", mutants=int(n_mut) if use_base else 0):
+        avail = np.asarray(available, dtype=bool)
+        if avail_idx is None:
+            avail_idx = np.flatnonzero(avail)
+        _check_avail(avail_idx, n_sel)
+        sd = float(y[valid > 0].std()) + 1e-6 if valid.sum() else 1.0
+        if use_base:
+            mutants = _mutate_plan_host(
+                rng, np.asarray(base_plan, dtype=bool), n_mut)
+        else:
+            mutants = np.zeros((n_mut, avail.shape[0]), dtype=bool)
+        shards = _usable_search_shards(num_shards, num_candidates)
+        fn = _bods_fn(int(num_candidates), int(n_mut), int(n_sel),
+                      bool(delta_fairness), bool(local_search), shards)
+        seed = jnp.uint32(int(rng.integers(0, 2**31 - 1)))
     with span("bods_acquire", candidates=int(num_candidates),
               mutants=int(n_mut), shards=shards):
-        plan, cand_est, ei = fn(
-            seed, jnp.asarray(times, jnp.float32),
-            jnp.asarray(_center(counts)),
-            jnp.asarray(np.asarray(counts) == 0), jnp.asarray(avail),
-            jnp.asarray(mu, jnp.float32), jnp.asarray(mutants),
-            jnp.asarray(bool(use_base)), jnp.asarray(F),
-            jnp.asarray((y - est) / sd * valid, jnp.float32),
-            jnp.asarray(valid, jnp.float32), jnp.float32(1.0 / sd),
-            jnp.float32(alpha), jnp.float32(beta), jnp.float32(time_scale),
-            jnp.float32(fairness_scale), jnp.float32(gp_noise))
-        out = np.asarray(plan), float(cand_est)
-    if not np.isfinite(float(ei)):
+        with span("bods_stage") as stage:
+            args = (
+                seed, jnp.asarray(times, jnp.float32),
+                jnp.asarray(_center(counts)),
+                jnp.asarray(np.asarray(counts) == 0), jnp.asarray(avail),
+                jnp.asarray(mu, jnp.float32), jnp.asarray(mutants),
+                jnp.asarray(bool(use_base)), jnp.asarray(F),
+                jnp.asarray((y - est) / sd * valid, jnp.float32),
+                jnp.asarray(valid, jnp.float32), jnp.float32(1.0 / sd),
+                jnp.float32(alpha), jnp.float32(beta),
+                jnp.float32(time_scale), jnp.float32(fairness_scale),
+                jnp.float32(gp_noise))
+            if tracing:
+                stage.annotate(arrays=len(args),
+                               bytes=sum(a.nbytes for a in args))
+        with span("bods_launch"):
+            plan, cand_est, ei = fn(*args)
+        if tracing:
+            with span("bods_wait"):
+                jax.block_until_ready((plan, cand_est, ei))
+        with span("bods_readback", what="plan", reads=2):
+            out = np.asarray(plan), float(cand_est)
+    with span("bods_readback", what="ei", reads=1):
+        ei = float(ei)
+    if not np.isfinite(ei):
         # argmax over a NaN posterior silently picks candidate 0.
         raise FloatingPointError(
-            f"BODS acquisition: non-finite EI {float(ei)} for the chosen "
+            f"BODS acquisition: non-finite EI {ei} for the chosen "
             "candidate (GP posterior broke down)")
     return out

@@ -18,11 +18,22 @@ construction — complete events on the same thread track nest by ts/dur in
 the viewer — and are thread-safe (one buffer, GIL-atomic appends; tid
 disambiguates tracks).
 
+Enabled, each span also opens a ``jax.profiler.TraceAnnotation`` of the
+same name and arguments, so under ``jax.profiler.trace`` the program's
+phases land on the profiler's host plane, on the device trace's clock.
+The tracer's own stamps are taken just inside the annotation, so the
+profiler's start of a span and the tracer's differ by one constant offset.
+Counts known only once the work is done are attached with
+``annotate(**kv)`` on the live span (to both records).
+
 Disabled (the default), ``span()`` returns a shared no-op context manager
-without allocating anything, and ``counter``/``instant`` return
-immediately: no RNG is touched, no arrays are built, so traced and
-untraced runs execute the SAME computation (``benchmarks/bench_obs.py``
-gates enabled-vs-disabled engine records bitwise and overhead <= 3%).
+without allocating anything (no annotation either), and
+``counter``/``instant`` return immediately: no RNG is touched, no arrays
+are built, so traced and untraced runs execute the SAME computation
+(``benchmarks/bench_obs.py`` gates enabled-vs-disabled engine records
+bitwise and overhead <= 3%). Span arguments are evaluated before the
+check, so a count that costs work to compute goes through ``annotate``
+behind ``enabled()``.
 
 Ownership: instrumented library code uses the module-global tracer via
 ``span``/``counter``/``instant``; ``repro.monitoring.session.ObsSession``
@@ -50,14 +61,29 @@ class _NoopSpan:
     def __exit__(self, *exc):
         return False
 
+    def annotate(self, **kv) -> None:
+        pass
+
 
 _NOOP = _NoopSpan()
 
+# ``jax.profiler.TraceAnnotation``, imported by the first live span so that
+# the tracer itself (and the report CLI) loads without JAX.
+_ANNOTATION = None
+
+
+def _annotation(name: str, args: Dict[str, Any]):
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        from jax.profiler import TraceAnnotation as _ANNOTATION
+    return _ANNOTATION(name, **args)
+
 
 class _Span:
-    """A live span: records one complete event on exit."""
+    """A live span: one profiler annotation around it, and one complete
+    event recorded on exit."""
 
-    __slots__ = ("_tracer", "_name", "_args", "_t0")
+    __slots__ = ("_tracer", "_name", "_args", "_t0", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, args: Dict[str, Any]):
         self._tracer = tracer
@@ -65,12 +91,21 @@ class _Span:
         self._args = args
 
     def __enter__(self):
+        self._ann = _annotation(self._name, self._args)
+        self._ann.__enter__()
         self._t0 = time.perf_counter_ns()
         return self
 
+    def annotate(self, **kv) -> None:
+        """Attach ``kv`` to the span's arguments (counts known only after
+        the work: bytes staged, reads made)."""
+        self._args.update(kv)
+        self._ann.set_metadata(**kv)
+
     def __exit__(self, *exc):
-        self._tracer._emit_complete(self._name, self._t0,
-                                    time.perf_counter_ns(), self._args)
+        t1 = time.perf_counter_ns()
+        self._ann.__exit__(*exc)
+        self._tracer._emit_complete(self._name, self._t0, t1, self._args)
         return False
 
 

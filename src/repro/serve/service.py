@@ -42,7 +42,7 @@ import numpy as np
 
 from repro.core.multijob import MultiJobEngine, RoundRecord
 from repro.experiment.spec import ExperimentSpec
-from repro.monitoring.trace import instant, span
+from repro.monitoring.trace import span
 from repro.serve.metrics import ServiceMetrics, ServiceReport
 from repro.serve.resilience import RoundWatchdog
 from repro.serve.traffic import TrafficEvent, trace_from_spec
@@ -253,7 +253,6 @@ class SchedulerService:
             queued_at = self.metrics.tenants[tenant].queued_at
             if queued_at is not None:
                 wait = float(now - queued_at)
-                instant("queue_wait", tenant=tenant, wait_s=wait)
                 if self.engine.events is not None:
                     self.engine.events.publish("serve.queue_wait", dict(
                         tenant=tenant, t=now, wait_s=wait))

@@ -44,6 +44,82 @@ def test_spans_nest_and_record_complete_events():
     assert outer["args"] == {"job": 3}
 
 
+def test_annotate_attaches_args_on_live_span_and_is_noop_when_disabled():
+    t = Tracer()
+    off = t.span("a")
+    off.annotate(bytes=3)            # the shared singleton ignores it
+    assert off is t.span("b") and t.num_events == 0
+    t.enabled = True
+    with t.span("stage", what="plan") as live:
+        live.annotate(arrays=2, bytes=12)
+    (ev,) = t.events()
+    assert ev["args"] == {"what": "plan", "arrays": 2, "bytes": 12}
+
+
+@pytest.mark.parametrize("on", [False, True])
+def test_profiler_annotation_built_only_for_live_spans(monkeypatch, on):
+    built = []
+
+    class Annotation:
+        def __init__(self, name, **args):
+            built.append((name, args))
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+        def set_metadata(self, **kv):
+            built[-1][1].update(kv)
+
+    monkeypatch.setattr(trace_mod, "_ANNOTATION", Annotation)
+    monkeypatch.setattr(trace_mod.get_tracer(), "enabled", on)
+    s = trace_mod.span("schedule", job=1)
+    assert (s is trace_mod._NOOP) is not on
+    with s as live:
+        live.annotate(bytes=4)
+    with Tracer(enabled=on).span("inner"):
+        pass
+    trace_mod.clear()
+    assert built == ([("schedule", {"job": 1, "bytes": 4}), ("inner", {})]
+                     if on else [])
+
+
+def test_spans_land_on_the_profilers_clock(tmp_path):
+    """Every live span is a TraceMe on the profiler's host plane whose start
+    lies one constant offset from the tracer's stamp: the offset a
+    ``bench_sync``-style annotation (opened between two clock reads) gives."""
+    import glob
+    import time
+
+    import jax
+
+    t = Tracer(enabled=True)
+    with jax.profiler.trace(str(tmp_path)):
+        a = time.perf_counter_ns()
+        with jax.profiler.TraceAnnotation("clock_sync"):
+            b = time.perf_counter_ns()
+        for i in range(12):
+            with t.span(f"phase{i}", i=i):
+                with t.span(f"inner{i}"):
+                    time.sleep(1e-4)
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    host = {}
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for ev in line.events:
+                    host.setdefault(ev.name, (ev.start_ns, ev.duration_ns))
+    s0, d0 = host["clock_sync"]
+    off = s0 + d0 / 2 - (a + b) / 2
+    evs = t.events()
+    assert len(evs) == 24
+    delta = [host[e["name"]][0] - e["ts"] * 1e3 for e in evs]
+    assert max(delta) - min(delta) < 50e3, delta
+    assert max(abs(d - off) for d in delta) < 50e3, (delta, off)
+
+
 def test_global_tracer_enable_disable():
     trace_mod.clear()
     assert not trace_mod.enabled()

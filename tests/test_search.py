@@ -374,3 +374,96 @@ def test_ctx_caches_computed_once():
     idx = ctx.available_indices()
     assert ctx.available_indices() is idx
     np.testing.assert_array_equal(idx, np.flatnonzero(ctx.available))
+
+
+# ---- tracing the fused decisions -----------------------------------------
+
+def _bods_decisions(traced, rounds=4, K=40):
+    """Decisions of one fused BODS scheduler from a fixed seed (later
+    rounds mutate the best observed plan); with ``traced`` the global
+    tracer is on and its events are returned."""
+    from repro.monitoring import trace as trace_mod
+
+    cm, pool, counts, occ = scenario(K, 3, 4)
+    sched = get_scheduler("bods", cost_model=cm, seed=5, num_candidates=32,
+                          search_backend="fused")
+    rng = np.random.default_rng(7)
+    out = []
+    trace_mod.clear()
+    if traced:
+        trace_mod.enable()
+    try:
+        for r in range(rounds):
+            ctx = make_ctx(pool, n_sel=4, occupied=occ, counts=counts.copy(),
+                           round_idx=r)
+            plan = sched.schedule(ctx)
+            out.append((plan.copy(), sched.last_estimated_cost))
+            sched.observe(ctx, plan, float(rng.random()))
+            counts += plan
+    finally:
+        trace_mod.disable()
+    events = trace_mod.get_tracer().events()
+    trace_mod.clear()
+    return out, events
+
+
+def test_traced_bods_decision_records_its_phases():
+    _, events = _bods_decisions(traced=True)
+    names = [e["name"] for e in events]
+    assert names.count("bods_acquire") == 4
+    # The last decision (events are recorded as their spans close).
+    mine = events[len(events) - 7:]
+    last = mine[5]
+    assert [e["name"] for e in mine] == [
+        "bods_prepare", "bods_stage", "bods_launch", "bods_wait",
+        "bods_readback", "bods_acquire", "bods_readback"]
+    by = {e["name"]: e for e in mine}
+    assert by["bods_prepare"]["args"] == {"mutants": 8}
+    assert by["bods_stage"]["args"]["arrays"] == 17
+    assert by["bods_stage"]["args"]["bytes"] > 40 * 4
+    reads = [e["args"] for e in mine if e["name"] == "bods_readback"]
+    assert reads == [{"what": "plan", "reads": 2}, {"what": "ei", "reads": 1}]
+    lo, hi = last["ts"], last["ts"] + last["dur"]
+    for e in mine[1:5]:
+        assert lo <= e["ts"] and e["ts"] + e["dur"] <= hi + 1e-3, e["name"]
+    assert by["bods_prepare"]["ts"] + by["bods_prepare"]["dur"] <= lo + 1e-3
+    assert mine[-1]["ts"] >= hi - 1e-3
+
+
+def test_traced_and_untraced_bods_decisions_are_identical():
+    untraced, none = _bods_decisions(traced=False)
+    traced, events = _bods_decisions(traced=True)
+    assert none == [] and events
+    for (p0, e0), (p1, e1) in zip(untraced, traced):
+        np.testing.assert_array_equal(p0, p1)
+        assert e0 == e1
+
+
+@pytest.mark.parametrize("name,factory,module", [
+    ("bods", "_bods_fn", "jit_bods_acquire"),
+    ("sa", "_sa_fn", "jit_sa_search"),
+    ("genetic", "_ga_fn", "jit_ga_search"),
+])
+def test_search_programs_are_named_for_their_searcher(monkeypatch, name,
+                                                      factory, module):
+    calls = []
+    real = getattr(search, factory)
+
+    def capture(*key):
+        fn = real(*key)
+
+        def call(*args):
+            calls.append((fn, args))
+            return fn(*args)
+        return call
+
+    monkeypatch.setattr(search, factory, capture)
+    pool = DevicePool.heterogeneous(30, 1, seed=0)
+    cm = CostModel(pool)
+    cm.calibrate([5.0], n_sel=4)
+    sched = get_scheduler(name, cost_model=cm, seed=0,
+                          search_backend="fused")
+    sched.schedule(make_ctx(pool, n_sel=4))
+    (fn, args), = calls
+    head = fn.lower(*args).as_text().splitlines()[0]
+    assert head.startswith(f"module @{module} "), head
