@@ -47,7 +47,7 @@ from __future__ import annotations
 
 import functools
 import logging
-from typing import Optional, Tuple
+from typing import NamedTuple, Optional, Tuple
 
 import numpy as np
 
@@ -698,9 +698,69 @@ def featurize_plans(times, counts_c, counts_zero, mu, plans, ts, fs,
     return feats, est_time, dfair
 
 
+# The acquisition body's 17 inputs in argument order, each with its word
+# type in the packed buffer (``_BodsLayout``).
+_BODS_FIELDS = (
+    ("seed", "u32"), ("times", "f32"), ("counts_c", "f32"),
+    ("counts_zero", "bool"), ("avail", "bool"), ("mu", "f32"),
+    ("mutants", "bool"), ("use_base", "bool"), ("F", "f32"),
+    ("resid", "f32"), ("valid", "f32"), ("inv_sd", "f32"), ("alpha", "f32"),
+    ("beta", "f32"), ("ts", "f32"), ("fs", "f32"), ("noise", "f32"))
+
+
+class _BodsLayout(NamedTuple):
+    """Where each of the 17 inputs of a fused BODS decision sits in the one
+    uint32 buffer that carries them to the device: ``(name, start, end,
+    shape, kind)`` word slices in argument order, and the buffer's
+    length."""
+
+    fields: Tuple[Tuple[str, int, int, Tuple[int, ...], str], ...]
+    words: int
+
+    def pack(self, *values) -> np.ndarray:
+        """Host side: the 17 values as one contiguous uint32 buffer. Float
+        fields are rounded to float32 (as ``jnp.asarray(v, jnp.float32)``
+        rounds) and stored bit-for-bit; booleans become 0/1 words; the seed
+        is one word."""
+        buf = np.empty(self.words, np.uint32)
+        as_f32 = buf.view(np.float32)
+        for (_, lo, hi, _, kind), v in zip(self.fields, values, strict=True):
+            (as_f32 if kind == "f32" else buf)[lo:hi] = np.ravel(v)
+        return buf
+
+    def unpack(self, packed):
+        """Traced inverse of ``pack``: static slices of the device buffer,
+        floats recovered by bitcast, booleans as ``!= 0``."""
+        import jax
+        import jax.numpy as jnp
+
+        as_f32 = jax.lax.bitcast_convert_type(packed, jnp.float32)
+        out = []
+        for _, lo, hi, shape, kind in self.fields:
+            v = as_f32[lo:hi] if kind == "f32" else packed[lo:hi]
+            out.append((v != 0 if kind == "bool" else v).reshape(shape))
+        return tuple(out)
+
+
 @functools.lru_cache(maxsize=None)
-def _bods_fn(num_candidates: int, n_mut: int, n_sel: int,
-             delta_fairness: bool, local_search: bool, num_shards: int = 1):
+def _bods_layout(K: int, L: int, d: int, n_mut: int) -> _BodsLayout:
+    """The packed layout for K devices, an L-long observation ring of d
+    features, and ``n_mut`` local-search mutants."""
+    shapes = ((), (K,), (K,), (K,), (K,), (K,), (n_mut, K), (), (L, d),
+              (L,), (L,)) + ((),) * 6
+    fields, lo = [], 0
+    for shape, (name, kind) in zip(shapes, _BODS_FIELDS):
+        hi = lo + int(np.prod(shape, dtype=np.int64))
+        fields.append((name, lo, hi, shape, kind))
+        lo = hi
+    return _BodsLayout(tuple(fields), lo)
+
+
+def _bods_body(num_candidates: int, n_mut: int, n_sel: int,
+               delta_fairness: bool, local_search: bool, num_shards: int = 1):
+    """The acquisition over its 17 unpacked inputs (traced, not jitted):
+    the whole decision for one lane, or with ``num_shards`` > 1 one shard's
+    block, to run under ``shard_map`` on the ``fleet`` axis."""
     import jax
     import jax.numpy as jnp
 
@@ -764,12 +824,10 @@ def _bods_fn(num_candidates: int, n_mut: int, n_sel: int,
         mu_c, sigma = gp_posterior(chol, w, m, F, feats, cand_est * inv_sd)
         return cands, cand_est, mu_c, sigma
 
-    # Both executors are named for the searcher, so the device trace reads
-    # ``jit_bods_acquire``.
     if N == 1:
-        def bods_acquire(seed, times, counts_c, counts_zero, avail, mu,
-                         mutants, use_base, F, resid, valid, inv_sd, alpha,
-                         beta, ts, fs, noise):
+        def acquire(seed, times, counts_c, counts_zero, avail, mu, mutants,
+                    use_base, F, resid, valid, inv_sd, alpha, beta, ts, fs,
+                    noise):
             ids = jnp.arange(P, dtype=jnp.int32)
             cands, cand_est, mu_c, sigma = block(
                 seed, ids, times, counts_c, counts_zero, avail, mu, mutants,
@@ -779,7 +837,7 @@ def _bods_fn(num_candidates: int, n_mut: int, n_sel: int,
             choice = jnp.argmax(ei)
             return cands[choice], cand_est[choice], ei[choice]
 
-        return jax.jit(bods_acquire)
+        return acquire
 
     # Candidate-axis sharding: each shard generates/featurizes/scores its
     # own Pb candidates (the per-candidate PRNG keeps the candidate SET
@@ -799,19 +857,42 @@ def _bods_fn(num_candidates: int, n_mut: int, n_sel: int,
         c = jnp.argmax(ei)
         return cands[c][None], cand_est[c][None], ei[c][None], ids[c][None]
 
+    return run_shard
+
+
+@functools.lru_cache(maxsize=None)
+def _bods_fn(num_candidates: int, n_mut: int, n_sel: int,
+             delta_fairness: bool, local_search: bool, num_shards: int,
+             layout: _BodsLayout):
+    """The jitted decision over ONE packed input buffer (``layout``):
+    unpack in-graph, then run ``_bods_body``."""
+    import jax
+    import jax.numpy as jnp
+
+    body = _bods_body(num_candidates, n_mut, n_sel, delta_fairness,
+                      local_search, num_shards)
+
+    # Both executors are named for the searcher, so the device trace reads
+    # ``jit_bods_acquire``.
+    if num_shards == 1:
+        def bods_acquire(packed):
+            return body(*layout.unpack(packed))
+
+        return jax.jit(bods_acquire)
+
     from jax.sharding import PartitionSpec as Psp
 
     from repro.core.shard import fleet_mesh
 
-    rep = Psp()
     sharded = jax.shard_map(
-        run_shard, mesh=fleet_mesh(N), in_specs=(rep,) * 17,
+        lambda packed: body(*layout.unpack(packed)),
+        mesh=fleet_mesh(num_shards), in_specs=(Psp(),),
         out_specs=(Psp("fleet", None), Psp("fleet"), Psp("fleet"),
                    Psp("fleet")),
         check_vma=False)
 
-    def bods_acquire(*args):
-        plans, ests, eis, gids = sharded(*args)
+    def bods_acquire(packed):
+        plans, ests, eis, gids = sharded(packed)
         # Max EI wins; ties break to the LOWEST global candidate id,
         # matching the single lane's first-argmax semantics.
         order = jnp.where(eis == jnp.max(eis), gids,
@@ -861,15 +942,25 @@ def bods_acquire(rng: np.random.Generator, times: np.ndarray,
     axis partitions across host platform devices without changing the
     candidate set.
 
+    The decision's inputs cross to the device as ONE packed buffer of
+    uint32 words (``_bods_layout``), in the body's argument order: the
+    seed (one word); times, centred counts (float32); the zero-count mask,
+    availability (0/1 words); mu (float32); the (n_mut, K) mutants and the
+    ``use_base`` flag (0/1 words); the ring's (L, d) features F, its
+    normalised residuals and valid mask (float32); then ``1/sd``, alpha,
+    beta, time scale, fairness scale and GP noise (float32). Floats are
+    rounded to float32 on the host and stored bit-for-bit; the program
+    slices the buffer at static offsets, bitcasts the float words back and
+    reads booleans as ``!= 0``. Every field is packed afresh each decision.
+
     Traced, the decision splits into ``bods_prepare`` (host work before the
-    call) and, inside ``bods_acquire``, ``bods_stage`` (host-to-device
-    transfers), ``bods_launch`` (the dispatch), ``bods_wait`` (the device
-    work; only when tracing, since the read-back blocks at the same point
-    anyway) and ``bods_readback`` (the plan and its estimate; the EI check
-    reads once more after the span).
+    call) and, inside ``bods_acquire``, ``bods_stage`` (packing and the one
+    host-to-device transfer), ``bods_launch`` (the dispatch), ``bods_wait``
+    (the device work; only when tracing, since the read-back blocks at the
+    same point anyway) and ``bods_readback`` (the plan and its estimate;
+    the EI check reads once more after the span).
     """
     import jax
-    import jax.numpy as jnp
 
     tracing = trace_enabled()
     use_base = base_plan is not None and local_search
@@ -885,28 +976,22 @@ def bods_acquire(rng: np.random.Generator, times: np.ndarray,
         else:
             mutants = np.zeros((n_mut, avail.shape[0]), dtype=bool)
         shards = _usable_search_shards(num_shards, num_candidates)
+        layout = _bods_layout(avail.shape[0], *np.shape(F), int(n_mut))
         fn = _bods_fn(int(num_candidates), int(n_mut), int(n_sel),
-                      bool(delta_fairness), bool(local_search), shards)
-        seed = jnp.uint32(int(rng.integers(0, 2**31 - 1)))
+                      bool(delta_fairness), bool(local_search), shards,
+                      layout)
+        seed = int(rng.integers(0, 2**31 - 1))
     with span("bods_acquire", candidates=int(num_candidates),
               mutants=int(n_mut), shards=shards):
         with span("bods_stage") as stage:
-            args = (
-                seed, jnp.asarray(times, jnp.float32),
-                jnp.asarray(_center(counts)),
-                jnp.asarray(np.asarray(counts) == 0), jnp.asarray(avail),
-                jnp.asarray(mu, jnp.float32), jnp.asarray(mutants),
-                jnp.asarray(bool(use_base)), jnp.asarray(F),
-                jnp.asarray((y - est) / sd * valid, jnp.float32),
-                jnp.asarray(valid, jnp.float32), jnp.float32(1.0 / sd),
-                jnp.float32(alpha), jnp.float32(beta),
-                jnp.float32(time_scale), jnp.float32(fairness_scale),
-                jnp.float32(gp_noise))
+            packed = jax.device_put(layout.pack(
+                seed, times, _center(counts), np.asarray(counts) == 0, avail,
+                mu, mutants, use_base, F, (y - est) / sd * valid, valid,
+                1.0 / sd, alpha, beta, time_scale, fairness_scale, gp_noise))
             if tracing:
-                stage.annotate(arrays=len(args),
-                               bytes=sum(a.nbytes for a in args))
+                stage.annotate(arrays=1, bytes=packed.nbytes)
         with span("bods_launch"):
-            plan, cand_est, ei = fn(*args)
+            plan, cand_est, ei = fn(packed)
         if tracing:
             with span("bods_wait"):
                 jax.block_until_ready((plan, cand_est, ei))

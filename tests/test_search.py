@@ -21,6 +21,7 @@ from repro.core import scoring, search
 from repro.core.cost import CostModel
 from repro.core.devices import DevicePool
 from repro.core.plans import (random_plans, repair_plans, validate_plan)
+from repro.core.schedulers import bods as bods_mod
 from repro.core.schedulers import get_scheduler
 from repro.core.schedulers.base import SchedulingContext
 
@@ -419,8 +420,12 @@ def test_traced_bods_decision_records_its_phases():
         "bods_readback", "bods_acquire", "bods_readback"]
     by = {e["name"]: e for e in mine}
     assert by["bods_prepare"]["args"] == {"mutants": 8}
-    assert by["bods_stage"]["args"]["arrays"] == 17
-    assert by["bods_stage"]["args"]["bytes"] > 40 * 4
+    # One packed transfer: K=40 devices, the MAX_OBS ring of NUM_FEATURES
+    # features, 32 // 4 = 8 mutants.
+    assert by["bods_stage"]["args"]["arrays"] == 1
+    layout = search._bods_layout(40, bods_mod.MAX_OBS, bods_mod.NUM_FEATURES,
+                                 8)
+    assert by["bods_stage"]["args"]["bytes"] == layout.words * 4
     reads = [e["args"] for e in mine if e["name"] == "bods_readback"]
     assert reads == [{"what": "plan", "reads": 2}, {"what": "ei", "reads": 1}]
     lo, hi = last["ts"], last["ts"] + last["dur"]
@@ -467,3 +472,134 @@ def test_search_programs_are_named_for_their_searcher(monkeypatch, name,
     (fn, args), = calls
     head = fn.lower(*args).as_text().splitlines()[0]
     assert head.startswith(f"module @{module} "), head
+
+
+# ---- the packed BODS inputs ------------------------------------------------
+
+def _bods_host_inputs(K, L, d, n_mut, seed, use_base, draw=0):
+    """Host inputs of one fused BODS decision as the scheduler hands them
+    over: float64 times, counts and mu, float32 ring arrays."""
+    rng = np.random.default_rng([K, L, n_mut, draw])
+    valid = (rng.random(L) < 0.7).astype(np.float32)
+    y = rng.normal(3.0, 1.0, L).astype(np.float32)
+    return dict(
+        seed=seed, times=rng.gamma(2.0, 3.0, K),
+        counts=rng.integers(0, 9, K).astype(np.float64),
+        avail=rng.random(K) < 0.8, mu=rng.uniform(0.3, 2.0, K),
+        mutants=rng.random((n_mut, K)) < 0.1, use_base=use_base,
+        F=rng.normal(size=(L, d)).astype(np.float32), y=y,
+        est=(y + rng.normal(0, 0.3, L)).astype(np.float32), valid=valid,
+        sd=float(y[valid > 0].std()) + 1e-6, alpha=4.0, beta=0.1,
+        ts=1.0 / 3.0, fs=0.7, noise=0.1)
+
+
+def _staged_one_by_one(x):
+    """Reference: the decision's 17 inputs staged as 17 separate device
+    arrays, one ``jnp`` conversion each."""
+    import jax.numpy as jnp
+
+    return (
+        jnp.uint32(x["seed"]), jnp.asarray(x["times"], jnp.float32),
+        jnp.asarray(search._center(x["counts"])),
+        jnp.asarray(np.asarray(x["counts"]) == 0), jnp.asarray(x["avail"]),
+        jnp.asarray(x["mu"], jnp.float32), jnp.asarray(x["mutants"]),
+        jnp.asarray(bool(x["use_base"])), jnp.asarray(x["F"]),
+        jnp.asarray((x["y"] - x["est"]) / x["sd"] * x["valid"],
+                    jnp.float32),
+        jnp.asarray(x["valid"], jnp.float32), jnp.float32(1.0 / x["sd"]),
+        jnp.float32(x["alpha"]), jnp.float32(x["beta"]),
+        jnp.float32(x["ts"]), jnp.float32(x["fs"]), jnp.float32(x["noise"]))
+
+
+def _packed(layout, x):
+    import jax
+
+    return jax.device_put(layout.pack(
+        x["seed"], x["times"], search._center(x["counts"]),
+        np.asarray(x["counts"]) == 0, x["avail"], x["mu"], x["mutants"],
+        x["use_base"], x["F"], (x["y"] - x["est"]) / x["sd"] * x["valid"],
+        x["valid"], 1.0 / x["sd"], x["alpha"], x["beta"], x["ts"], x["fs"],
+        x["noise"]))
+
+
+def _assert_bit_equal(got, want, what):
+    got, want = np.asarray(got), np.asarray(want)
+    assert got.dtype == want.dtype, (what, got.dtype, want.dtype)
+    assert got.shape == want.shape, (what, got.shape, want.shape)
+    assert got.tobytes() == want.tobytes(), what
+
+
+@pytest.mark.parametrize("K,L,d,n_mut", [(100, 256, 6, 32), (37, 16, 6, 8)])
+@pytest.mark.parametrize("use_base", [True, False])
+def test_bods_packed_inputs_round_trip_bit_exact(K, L, d, n_mut, use_base):
+    import jax
+
+    layout = search._bods_layout(K, L, d, n_mut)
+    assert layout.words == 1 + 5 * K + n_mut * K + 1 + L * d + 2 * L + 6
+    unpack = jax.jit(layout.unpack)
+    for seed in (0, 1, 12345, 2**31 - 2):
+        x = _bods_host_inputs(K, L, d, n_mut, seed, use_base, draw=seed)
+        assert (search._center(x["counts"]) < 0).any()
+        got = unpack(_packed(layout, x))
+        want = _staged_one_by_one(x)
+        assert len(got) == len(want) == len(layout.fields) == 17
+        for (name, *_), g, w in zip(layout.fields, got, want):
+            _assert_bit_equal(g, w, (name, seed))
+
+
+@pytest.mark.parametrize("use_base", [True, False])
+def test_packed_bods_program_matches_unpacked_body(monkeypatch, use_base):
+    """The buffer ``bods_acquire`` stages unpacks to the inputs staged one
+    by one; ``jit_bods_acquire`` on it decides exactly as the 17-argument
+    body jitted on those inputs, and ``bods_acquire`` returns that
+    decision."""
+    import copy
+
+    import jax
+
+    K, L, d, P, n_mut, n_sel = 40, 24, 6, 32, 8, 4
+    calls = []
+    real = search._bods_fn
+
+    def capture(*key):
+        fn = real(*key)
+
+        def call(packed):
+            out = fn(packed)
+            calls.append((key, packed, out))
+            return out
+        return call
+
+    monkeypatch.setattr(search, "_bods_fn", capture)
+    body = jax.jit(search._bods_body(P, n_mut, n_sel, False, True))
+    for draw in range(3):
+        x = _bods_host_inputs(K, L, d, n_mut, 0, use_base, draw=draw)
+        x["avail"][:n_sel] = True
+        base = np.zeros(K, bool)
+        base[np.flatnonzero(x["avail"])[:n_sel]] = True
+        rng = np.random.default_rng(100 + draw)
+        twin = copy.deepcopy(rng)
+        plan, est = search.bods_acquire(
+            rng, x["times"], x["counts"], x["avail"], x["mu"], n_sel,
+            F=x["F"], y=x["y"], est=x["est"], valid=x["valid"],
+            base_plan=base if use_base else None, alpha=x["alpha"],
+            beta=x["beta"], time_scale=x["ts"], fairness_scale=x["fs"],
+            delta_fairness=False, num_candidates=P, n_mut=n_mut,
+            local_search=True, gp_noise=x["noise"])
+        # The host draws of bods_prepare, replayed: mutants, then the seed.
+        x["mutants"] = (search._mutate_plan_host(twin, base, n_mut)
+                        if use_base else np.zeros((n_mut, K), bool))
+        x["seed"] = int(twin.integers(0, 2**31 - 1))
+        (key, packed, got), = calls
+        calls.clear()
+        layout = key[-1]
+        assert layout == search._bods_layout(K, L, d, n_mut)
+        staged = _staged_one_by_one(x)
+        for (name, *_), g, w in zip(layout.fields, jax.jit(layout.unpack)(
+                packed), staged):
+            _assert_bit_equal(g, w, (name, draw))
+        want = body(*staged)
+        for what, g, w in zip(("plan", "est", "ei"), got, want):
+            _assert_bit_equal(g, w, (what, draw))
+        np.testing.assert_array_equal(plan, np.asarray(got[0]))
+        assert est == float(got[1])
