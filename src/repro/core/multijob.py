@@ -321,21 +321,22 @@ class MultiJobEngine:
                 plan = self.scheduler.schedule(ctx)
                 rung = decision_ms = None
                 gov_est = getattr(self.scheduler, "last_estimated_cost", None)
-        with span("dispatch", job=job, round=js.round_idx):
+        with span("dispatch", job=job, round=js.round_idx) as dispatch:
             fe = self.fault_engine
             # Realized time includes any remaining busy time (release_horizon > 0).
             # Preallocated buffers: valid until this launch returns (nothing
             # below stores a view of them).
-            times = self.pool.sample_times_into(
-                job, js.config.local_epochs, self._times_buf)
-            if fe is not None:
-                # Straggler slowdown multiplies COMPUTE time, not queueing wait.
-                slow = fe.straggler_multipliers(job, js.round_idx)
-                if slow is not None:
-                    times *= slow
-            np.subtract(self.pool.busy_until, now, out=self._wait_buf)
-            np.maximum(self._wait_buf, 0.0, out=self._wait_buf)
-            times += self._wait_buf
+            with span("pool_sample", k=self.pool.num_devices):
+                times = self.pool.sample_times_into(
+                    job, js.config.local_epochs, self._times_buf)
+                if fe is not None:
+                    # Straggler slowdown multiplies COMPUTE time, not queueing wait.
+                    slow = fe.straggler_multipliers(job, js.round_idx)
+                    if slow is not None:
+                        times *= slow
+                np.subtract(self.pool.busy_until, now, out=self._wait_buf)
+                np.maximum(self._wait_buf, 0.0, out=self._wait_buf)
+                times += self._wait_buf
             sel_ids = np.flatnonzero(plan)
 
             # Straggler mitigation: with over-provisioning the round ends when the
@@ -438,10 +439,15 @@ class MultiJobEngine:
             if begin is not None:
                 begin(job, survivors, js.round_idx)
 
+            dropped = np.concatenate(
+                [dropped_straggler, failed, deadline_dropped])
+            # The over-selection cut (recorded only while tracing): devices
+            # scheduled, the n_sel fastest kept, every device left out.
+            dispatch.annotate(scheduled=int(sel_ids.size), kept=int(keep.size),
+                              dropped=int(dropped.size))
             self._in_flight[job] = dict(
                 plan=plan, survivors=survivors, counted=counted, failed=failed,
-                dropped=np.concatenate(
-                    [dropped_straggler, failed, deadline_dropped]),
+                dropped=dropped,
                 corrupt=corrupt_ids, degraded=degraded,
                 t_start=now, cost=cost, fairness=fairness, round_time=round_time,
                 est_cost=gov_est, rung=rung, decision_ms=decision_ms,

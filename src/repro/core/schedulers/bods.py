@@ -38,6 +38,7 @@ from repro.core import search
 from repro.core.plans import gumbel_topk_plans, random_plans, repair_plans
 from repro.core.schedulers.base import SchedulerBase, SchedulingContext
 from repro.experiment.registry import register_scheduler
+from repro.monitoring.trace import span
 
 MAX_OBS = 256
 NUM_FEATURES = 6
@@ -302,6 +303,7 @@ class BODSScheduler(SchedulerBase):
     # ---- Algorithm 1, Lines 6-7: realized cost becomes an observation ----
 
     def observe(self, ctx: SchedulingContext, plan: np.ndarray, realized_cost: float) -> None:
-        feat = self._featurize(ctx, plan[None])[0]
-        est = float(self._own_cost_of(ctx, plan[None])[0])
-        self._push(ctx.job, plan, feat, realized_cost, est)
+        with span("bods_observe", k=int(plan.shape[0])):
+            feat = self._featurize(ctx, plan[None])[0]
+            est = float(self._own_cost_of(ctx, plan[None])[0])
+            self._push(ctx.job, plan, feat, realized_cost, est)

@@ -954,25 +954,31 @@ def bods_acquire(rng: np.random.Generator, times: np.ndarray,
     reads booleans as ``!= 0``. Every field is packed afresh each decision.
 
     Traced, the decision splits into ``bods_prepare`` (host work before the
-    call) and, inside ``bods_acquire``, ``bods_stage`` (packing and the one
-    host-to-device transfer), ``bods_launch`` (the dispatch), ``bods_wait``
-    (the device work; only when tracing, since the read-back blocks at the
-    same point anyway) and ``bods_readback`` (the plan and its estimate;
-    the EI check reads once more after the span).
+    call; it counts the fleet's ``k`` devices and the ``available`` ones,
+    and holds ``bods_mutate``, the host mutant draw) and, inside
+    ``bods_acquire``, ``bods_stage`` (``bods_pack``, the packing, then
+    ``bods_put``, the one host-to-device transfer), ``bods_launch`` (the
+    dispatch), ``bods_wait`` (the device work; only when tracing, since the
+    read-back blocks at the same point anyway) and ``bods_readback`` (the
+    plan and its estimate; the EI check reads once more after the span).
     """
     import jax
 
     tracing = trace_enabled()
     use_base = base_plan is not None and local_search
-    with span("bods_prepare", mutants=int(n_mut) if use_base else 0):
+    with span("bods_prepare",
+              mutants=int(n_mut) if use_base else 0) as prepare:
         avail = np.asarray(available, dtype=bool)
         if avail_idx is None:
             avail_idx = np.flatnonzero(avail)
+        prepare.annotate(k=int(avail.shape[0]), available=int(avail_idx.size))
         _check_avail(avail_idx, n_sel)
         sd = float(y[valid > 0].std()) + 1e-6 if valid.sum() else 1.0
         if use_base:
-            mutants = _mutate_plan_host(
-                rng, np.asarray(base_plan, dtype=bool), n_mut)
+            with span("bods_mutate", k=int(avail.shape[0]),
+                      mutants=int(n_mut)):
+                mutants = _mutate_plan_host(
+                    rng, np.asarray(base_plan, dtype=bool), n_mut)
         else:
             mutants = np.zeros((n_mut, avail.shape[0]), dtype=bool)
         shards = _usable_search_shards(num_shards, num_candidates)
@@ -984,10 +990,14 @@ def bods_acquire(rng: np.random.Generator, times: np.ndarray,
     with span("bods_acquire", candidates=int(num_candidates),
               mutants=int(n_mut), shards=shards):
         with span("bods_stage") as stage:
-            packed = jax.device_put(layout.pack(
-                seed, times, _center(counts), np.asarray(counts) == 0, avail,
-                mu, mutants, use_base, F, (y - est) / sd * valid, valid,
-                1.0 / sd, alpha, beta, time_scale, fairness_scale, gp_noise))
+            with span("bods_pack", words=layout.words):
+                buf = layout.pack(
+                    seed, times, _center(counts), np.asarray(counts) == 0,
+                    avail, mu, mutants, use_base, F, (y - est) / sd * valid,
+                    valid, 1.0 / sd, alpha, beta, time_scale, fairness_scale,
+                    gp_noise)
+            with span("bods_put", bytes=buf.nbytes):
+                packed = jax.device_put(buf)
             if tracing:
                 stage.annotate(arrays=1, bytes=packed.nbytes)
         with span("bods_launch"):

@@ -151,3 +151,86 @@ def test_summary_reports_mean_round_time():
     for v in eng.summary().values():
         assert v["mean_round_time"] == pytest.approx(
             v["total_round_time"] / v["rounds"])
+
+
+# ---- the over-selection cut at fleet scale ---------------------------------
+
+def _fleet_engine(traced, K=20_000, n_sel=30, rounds=3):
+    """One job over K devices, ``n_sel`` a round over-selected by 1.3, a
+    random scheduler; returns the engine after ``rounds`` rounds, each
+    round's scheduled plan and drawn device times, and the tracer's events
+    (empty untraced)."""
+    from repro.monitoring import trace as trace_mod
+
+    pool = DevicePool.heterogeneous(K, 1, seed=4)
+    cm = CostModel(pool, alpha=4.0, beta=0.25)
+    cm.calibrate([5.0], n_sel=n_sel)
+    sched = get_scheduler("random", cost_model=cm, seed=0)
+    rt = SyntheticRuntime(num_jobs=1, num_devices=K, seed=2)
+    eng = MultiJobEngine(tiny_jobs(1, max_rounds=rounds), pool, cm, sched,
+                         rt, n_sel=n_sel, over_provision=1.3)
+    plans, drawn = [], []
+    schedule, sample = sched.schedule, pool.sample_times_into
+
+    def keep_plan(ctx):
+        plans.append(schedule(ctx).copy())
+        return plans[-1]
+
+    def keep_times(job, tau, out):
+        drawn.append(sample(job, tau, out).copy())
+        return out
+
+    sched.schedule, pool.sample_times_into = keep_plan, keep_times
+    trace_mod.clear()
+    if traced:
+        trace_mod.enable()
+    try:
+        eng.run()
+    finally:
+        trace_mod.disable()
+    events = trace_mod.get_tracer().events()
+    trace_mod.clear()
+    return eng, plans, drawn, events
+
+
+def test_over_selection_keeps_the_fastest_against_numpy():
+    """K=20,000, a target of 30 over-selected by 1.3: each round schedules
+    39 devices, keeps the 30 whose drawn times are smallest (plain numpy
+    below), drops the other 9, and lasts as long as the slowest kept one.
+    Scheduled devices are free at launch, so the wait adds nothing."""
+    eng, plans, drawn, _ = _fleet_engine(traced=False)
+    assert len(eng.records) == len(plans) == len(drawn) == 3
+    for rec, plan, t in zip(eng.records, plans, drawn):
+        sched_ids = np.flatnonzero(plan)
+        assert sched_ids.size == 39
+        fastest = sched_ids[np.argsort(t[sched_ids])[:30]]
+        np.testing.assert_array_equal(np.sort(rec.device_ids),
+                                      np.sort(fastest))
+        np.testing.assert_array_equal(np.sort(rec.dropped),
+                                      np.setdiff1d(sched_ids, fastest))
+        assert rec.round_time == t[fastest].max()
+        assert rec.round_time < t[sched_ids].max()
+
+
+def test_traced_dispatch_counts_the_cut_and_times_the_draw():
+    """Traced, ``dispatch`` carries the cut's counts and holds the
+    ``pool_sample`` span; the records equal the untraced run's bit for
+    bit."""
+    untraced, *_ = _fleet_engine(traced=False)
+    traced, _, _, events = _fleet_engine(traced=True)
+    dispatch = [e for e in events if e["name"] == "dispatch"]
+    sample = [e for e in events if e["name"] == "pool_sample"]
+    assert len(dispatch) == len(sample) == 3
+    for d, s in zip(dispatch, sample):
+        assert d["args"] == {"job": 0, "round": d["args"]["round"],
+                             "scheduled": 39, "kept": 30, "dropped": 9}
+        assert s["args"] == {"k": 20_000}
+        assert d["ts"] <= s["ts"] and (s["ts"] + s["dur"]
+                                       <= d["ts"] + d["dur"] + 1e-3)
+    assert len(untraced.records) == len(traced.records)
+    for a, b in zip(untraced.records, traced.records):
+        for f in ("t_start", "t_end", "round_time", "cost", "fairness",
+                  "loss", "accuracy"):
+            assert getattr(a, f) == getattr(b, f), f
+        np.testing.assert_array_equal(a.device_ids, b.device_ids)
+        np.testing.assert_array_equal(a.dropped, b.dropped)

@@ -412,27 +412,46 @@ def test_traced_bods_decision_records_its_phases():
     _, events = _bods_decisions(traced=True)
     names = [e["name"] for e in events]
     assert names.count("bods_acquire") == 4
-    # The last decision (events are recorded as their spans close).
-    mine = events[len(events) - 7:]
-    last = mine[5]
+    assert names.count("bods_observe") == 4
+    # The last decision and its observation (events are recorded as their
+    # spans close).
+    mine = events[len(events) - 11:]
     assert [e["name"] for e in mine] == [
-        "bods_prepare", "bods_stage", "bods_launch", "bods_wait",
-        "bods_readback", "bods_acquire", "bods_readback"]
+        "bods_mutate", "bods_prepare", "bods_pack", "bods_put", "bods_stage",
+        "bods_launch", "bods_wait", "bods_readback", "bods_acquire",
+        "bods_readback", "bods_observe"]
     by = {e["name"]: e for e in mine}
-    assert by["bods_prepare"]["args"] == {"mutants": 8}
-    # One packed transfer: K=40 devices, the MAX_OBS ring of NUM_FEATURES
-    # features, 32 // 4 = 8 mutants.
-    assert by["bods_stage"]["args"]["arrays"] == 1
+    # K=40 devices, 8 of them busy (``scenario``), 32 // 4 = 8 mutants.
+    assert by["bods_prepare"]["args"] == {"mutants": 8, "k": 40,
+                                          "available": 32}
+    assert by["bods_mutate"]["args"] == {"k": 40, "mutants": 8}
+    assert by["bods_observe"]["args"] == {"k": 40}
+    # One packed transfer of the MAX_OBS ring of NUM_FEATURES features.
     layout = search._bods_layout(40, bods_mod.MAX_OBS, bods_mod.NUM_FEATURES,
                                  8)
-    assert by["bods_stage"]["args"]["bytes"] == layout.words * 4
+    assert by["bods_stage"]["args"] == {"arrays": 1,
+                                        "bytes": layout.words * 4}
+    assert by["bods_pack"]["args"] == {"words": layout.words}
+    assert by["bods_put"]["args"] == {"bytes": layout.words * 4}
     reads = [e["args"] for e in mine if e["name"] == "bods_readback"]
     assert reads == [{"what": "plan", "reads": 2}, {"what": "ei", "reads": 1}]
-    lo, hi = last["ts"], last["ts"] + last["dur"]
-    for e in mine[1:5]:
-        assert lo <= e["ts"] and e["ts"] + e["dur"] <= hi + 1e-3, e["name"]
-    assert by["bods_prepare"]["ts"] + by["bods_prepare"]["dur"] <= lo + 1e-3
-    assert mine[-1]["ts"] >= hi - 1e-3
+
+    def inside(inner, outer):
+        return (outer["ts"] <= inner["ts"] and inner["ts"] + inner["dur"]
+                <= outer["ts"] + outer["dur"] + 1e-3)
+
+    acquire = by["bods_acquire"]
+    for e in mine[4:8]:
+        assert inside(e, acquire), e["name"]
+    assert inside(by["bods_mutate"], by["bods_prepare"])
+    assert inside(by["bods_pack"], by["bods_stage"])
+    assert inside(by["bods_put"], by["bods_stage"])
+    assert by["bods_pack"]["ts"] + by["bods_pack"]["dur"] <= (
+        by["bods_put"]["ts"] + 1e-3)
+    assert by["bods_prepare"]["ts"] + by["bods_prepare"]["dur"] <= (
+        acquire["ts"] + 1e-3)
+    assert mine[-2]["ts"] >= acquire["ts"] + acquire["dur"] - 1e-3
+    assert mine[-1]["ts"] >= mine[-2]["ts"] + mine[-2]["dur"] - 1e-3
 
 
 def test_traced_and_untraced_bods_decisions_are_identical():
@@ -442,6 +461,32 @@ def test_traced_and_untraced_bods_decisions_are_identical():
     for (p0, e0), (p1, e1) in zip(untraced, traced):
         np.testing.assert_array_equal(p0, p1)
         assert e0 == e1
+
+
+def test_fused_bods_decision_at_fleet_scale_matches_float64_cost():
+    """Fused BODS over K=20,000 devices with 512 candidates (the fleet
+    cell's count), a selection of 39: a first decision, then one that
+    mutates the best observed plan. Each plan is a (K,) mask of exactly 39
+    available devices, and its estimate is within 1e-5 of Formula 2
+    re-scored in float64 numpy."""
+    K, n_sel = 20_000, 39
+    cm, pool, counts, occ = scenario(K, 11, n_sel)
+    assert cm.delta_fairness
+    sched = get_scheduler("bods", cost_model=cm, seed=3, num_candidates=512,
+                          search_backend="fused")
+    for r in range(2):
+        ctx = make_ctx(pool, n_sel=n_sel, occupied=occ, counts=counts.copy(),
+                       round_idx=r)
+        plan = sched.schedule(ctx)
+        assert plan.dtype == bool and plan.shape == (K,)
+        assert int(plan.sum()) == n_sel and ctx.available[plan].all()
+        t = np.asarray(ctx.expected_times, np.float64)
+        ref = (cm.alpha * t[plan].max() / cm.time_scale
+               + cm.beta * (np.var(counts + plan) - np.var(counts))
+               / cm.fairness_scale)
+        assert abs(sched.last_estimated_cost - ref) <= 1e-5 * abs(ref)
+        sched.observe(ctx, plan, 1.1 * ref)
+        counts += plan
 
 
 @pytest.mark.parametrize("name,factory,module", [
