@@ -756,6 +756,135 @@ def _bods_layout(K: int, L: int, d: int, n_mut: int) -> _BodsLayout:
     return _BodsLayout(tuple(fields), lo)
 
 
+_RADIX_BITS = 4  # key bits a radix-select pass settles; 32 // it passes
+
+
+def _topk_mask(keys, k: int):
+    """(B, K) float32 -> (B, K) bool: each row's ``k`` largest keys, the
+    mask ``jax.lax.top_k`` and a scatter of its indices give, without a
+    sort.
+
+    Keys map to order-preserving uint32 words (negatives have every bit
+    flipped, the rest the sign bit set, so ``-inf`` is lowest and ``-0.0``
+    sits just below ``+0.0``). A radix select then settles each row's
+    k-th largest word ``T`` from the top bit down, ``_RADIX_BITS`` at a
+    time: a pass counts the words at or above each candidate prefix and
+    keeps the highest prefix that still counts ``k``. The passes are fixed
+    in number, whatever the data. The mask is every word above ``T`` and,
+    of the words equal to ``T``, the lowest-index ``k - count(above)``:
+    ``top_k``'s tie rule. The ranking of ties runs only when some row
+    holds more ties at ``T`` than it takes: it settles the index of each
+    row's last tie taken from the top bit down, one counting pass a bit.
+    Keys hold no NaN."""
+    import jax
+    import jax.numpy as jnp
+
+    B, K = keys.shape
+    bits = jax.lax.bitcast_convert_type(keys, jnp.uint32)
+    # Drawn once: the passes below must not each recompute the keys.
+    words = jax.lax.optimization_barrier(
+        jnp.where(bits >> 31 != 0, ~bits, bits | jnp.uint32(1 << 31)))
+    digits = jnp.arange(1, 1 << _RADIX_BITS, dtype=jnp.uint32)
+
+    def settle(i, prefix):
+        shift = (32 - _RADIX_BITS * (i + 1)).astype(jnp.uint32)
+        cand = prefix[:, None] | (digits[None, :] << shift)        # (B, D)
+        at_least = jnp.sum(words[:, :, None] >= cand[:, None, :], axis=1,
+                           dtype=jnp.int32)                        # (B, D)
+        digit = jnp.sum(at_least >= k, axis=1, dtype=jnp.uint32)
+        return prefix | (digit << shift)
+
+    T = jax.lax.fori_loop(0, 32 // _RADIX_BITS, settle,
+                          jnp.zeros((B,), jnp.uint32))[:, None]
+    above, tied = words > T, words == T
+    take = k - jnp.sum(above, axis=1, dtype=jnp.int32)
+    spare = jnp.sum(tied, axis=1, dtype=jnp.int32) > take
+
+    def rank_ties():
+        # The highest index below which fewer than ``take`` ties lie is
+        # the index of the last tie taken.
+        idx = jnp.arange(K, dtype=jnp.int32)
+        top = max(K - 1, 1).bit_length()
+
+        def settle_index(i, last):
+            cand = last | (1 << (top - 1 - i))
+            fewer = jnp.sum(tied & (idx < cand[:, None]), axis=1,
+                            dtype=jnp.int32) < take
+            return jnp.where(fewer, cand, last)
+
+        last = jax.lax.fori_loop(0, top, settle_index,
+                                 jnp.zeros((B,), jnp.int32))[:, None]
+        return above | (tied & (idx <= last) & (take[:, None] > 0))
+
+    return jax.lax.cond(jnp.any(spare), rank_ties, lambda: above | tied)
+
+
+def _bods_candidates(seed, ids, times, counts_c, avail, mutants, use_base, *,
+                     n_rand: int, n_mut: int, n_sel: int, local_search: bool):
+    """(B,) global candidate ids -> (B, K) bool plans, the n_sel devices of
+    each candidate.
+
+    PRNG is PER CANDIDATE (``fold_in`` of the decision seed by candidate
+    id), so the candidate set is a pure function of (seed, id), invariant
+    to how the candidate axis is partitioned across shards. Threefry keys
+    on purpose: the fast ``rbg`` impl draws DIFFERENT bits for the same key
+    under different vmap batch sizes, which would make the candidate set
+    depend on the shard count. Layout matches the host path: ids
+    [0, n_rand) uniform Gumbel top-k, the rest structured
+    (availability-logit) Gumbel top-k, and when local search is armed and
+    ``use_base`` holds, ids [0, n_mut) become repaired mutants of the
+    incumbent.
+
+    A plan is selected from its float32 keys (Gumbel-perturbed logits,
+    ``-inf`` where unavailable) by ``_topk_mask``: order-preserving uint32
+    words, a radix select of the n_sel-th largest and ``top_k``'s tie rule
+    (lowest index first), so the plans are those of ``top_k`` bit for bit,
+    without a sort. The (B, K) keys are drawn once, before the select's
+    passes read them.
+    Only the mutant rows are repaired: the head ``min(n_mut, B)`` rows of a
+    block hold every id below n_mut, and a block that owns none (a later
+    shard) skips the repair."""
+    import jax
+    import jax.numpy as jnp
+
+    K = times.shape[0]
+    t_norm = _norm01_traced(times, avail)
+    c_norm = _norm01_traced(counts_c, jnp.ones(K, bool))
+    base_key = jax.random.key(seed)
+
+    def gumbel_keys(cid):
+        kg, kw1, kw2, _ = jax.random.split(jax.random.fold_in(base_key, cid),
+                                           4)
+        w_time = jax.random.uniform(kw1, (), minval=0.0, maxval=6.0)
+        w_fair = jax.random.uniform(kw2, (), minval=0.0, maxval=4.0)
+        logits = jnp.where(cid >= n_rand,
+                           -w_time * t_norm - w_fair * c_norm, 0.0)
+        return jnp.where(avail, logits + jax.random.gumbel(kg, (K,)),
+                         -jnp.inf)
+
+    plans = _topk_mask(jax.vmap(gumbel_keys)(ids), n_sel) & avail
+    head = ids[:min(n_mut, ids.shape[0])]
+    if not local_search or head.shape[0] == 0:
+        return plans
+
+    def repair(plans):
+        # Row-wise twin of ``repair_plans_jax`` on each mutant of the best
+        # observed plan.
+        def repair_keys(cid):
+            kr = jax.random.split(jax.random.fold_in(base_key, cid), 4)[3]
+            mut = mutants[jnp.minimum(cid, n_mut - 1)]
+            return jnp.where(avail, (mut & avail) +
+                             jax.random.uniform(kr, (K,)), -jnp.inf)
+
+        rplans = _topk_mask(jax.vmap(repair_keys)(head), n_sel) & avail
+        keep = plans[:head.shape[0]]
+        return plans.at[:head.shape[0]].set(
+            jnp.where((head < n_mut)[:, None], rplans, keep))
+
+    return jax.lax.cond(use_base & (head[0] < n_mut), repair,
+                        lambda plans: plans, plans)
+
+
 def _bods_body(num_candidates: int, n_mut: int, n_sel: int,
                delta_fairness: bool, local_search: bool, num_shards: int = 1):
     """The acquisition over its 17 unpacked inputs (traced, not jitted):
@@ -769,53 +898,14 @@ def _bods_body(num_candidates: int, n_mut: int, n_sel: int,
     N = num_shards
     Pb = P // N  # candidates this shard owns (P itself when unsharded)
 
-    def gen_candidates(seed, ids, times, counts_c, avail, mutants, use_base):
-        """(B,) global candidate ids -> (B, K) bool plans. PRNG is PER
-        CANDIDATE (``fold_in`` of the decision seed by candidate id), so the
-        candidate set is a pure function of (seed, id) — invariant to how
-        the candidate axis is partitioned across shards. Threefry keys on
-        purpose: the fast ``rbg`` impl draws DIFFERENT bits for the same
-        key under different vmap batch sizes, which would make the
-        candidate set depend on the shard count. Layout matches the host
-        path: ids [0, n_rand) uniform Gumbel top-k, the rest structured
-        (availability-logit) Gumbel top-k, and when local search is armed
-        ids [0, n_mut) become repaired mutants of the incumbent."""
-        K = times.shape[0]
-        t_norm = _norm01_traced(times, avail)
-        c_norm = _norm01_traced(counts_c, jnp.ones(K, bool))
-        base_key = jax.random.key(seed)
-
-        def one(cid):
-            k = jax.random.fold_in(base_key, cid)
-            kg, kw1, kw2, kr = jax.random.split(k, 4)
-            w_time = jax.random.uniform(kw1, (), minval=0.0, maxval=6.0)
-            w_fair = jax.random.uniform(kw2, (), minval=0.0, maxval=4.0)
-            logits = jnp.where(cid >= n_rand,
-                               -w_time * t_norm - w_fair * c_norm, 0.0)
-            g = jnp.where(avail, logits + jax.random.gumbel(kg, (K,)),
-                          -jnp.inf)
-            _, ti = jax.lax.top_k(g, n_sel)
-            plan = jnp.zeros((K,), bool).at[ti].set(True) & avail
-            if local_search:
-                # Row-wise twin of ``repair_plans_jax`` on this candidate's
-                # mutant of the best observed plan.
-                mut = mutants[jnp.minimum(cid, n_mut - 1)]
-                rk = jnp.where(avail, (mut & avail) +
-                               jax.random.uniform(kr, (K,)), -jnp.inf)
-                _, ri = jax.lax.top_k(rk, n_sel)
-                rplan = jnp.zeros((K,), bool).at[ri].set(True) & avail
-                plan = jnp.where(use_base & (cid < n_mut), rplan, plan)
-            return plan
-
-        return jax.vmap(one)(ids)
-
     def block(seed, ids, times, counts_c, counts_zero, avail, mu, mutants,
               use_base, F, resid, valid, inv_sd, alpha, beta, ts, fs,
               noise):
         """One candidate block end-to-end: generation, featurization, GP
         posterior. Returns (plans, est cost, posterior mean, stddev)."""
-        cands = gen_candidates(seed, ids, times, counts_c, avail, mutants,
-                               use_base)
+        cands = _bods_candidates(seed, ids, times, counts_c, avail, mutants,
+                                 use_base, n_rand=n_rand, n_mut=n_mut,
+                                 n_sel=n_sel, local_search=local_search)
         feats, est_time, dfair = featurize_plans(
             times, counts_c, counts_zero, mu, cands, ts, fs, n_sel,
             delta_fairness)
@@ -940,7 +1030,13 @@ def bods_acquire(rng: np.random.Generator, times: np.ndarray,
     one decision seed (the (P, K) noise block is the one unavoidable
     K-wide draw in this module), so with ``num_shards`` > 1 the candidate
     axis partitions across host platform devices without changing the
-    candidate set.
+    candidate set. Each candidate's plan is its n_sel largest keys, found
+    without a sort (``_topk_mask``): the float32 keys map to
+    order-preserving uint32 words, a radix select of fixed passes settles
+    the n_sel-th largest, and ties there go to the lowest device index, as
+    ``lax.top_k`` breaks them. Only the ``n_mut`` mutant rows are repaired,
+    and only with a base plan; the ``bods_acquire`` span records ``select``
+    and the ``repair_rows`` that ran.
 
     The decision's inputs cross to the device as ONE packed buffer of
     uint32 words (``_bods_layout``), in the body's argument order: the
@@ -988,7 +1084,8 @@ def bods_acquire(rng: np.random.Generator, times: np.ndarray,
                       layout)
         seed = int(rng.integers(0, 2**31 - 1))
     with span("bods_acquire", candidates=int(num_candidates),
-              mutants=int(n_mut), shards=shards):
+              mutants=int(n_mut), shards=shards, select="radix",
+              repair_rows=int(n_mut) if use_base else 0):
         with span("bods_stage") as stage:
             with span("bods_pack", words=layout.words):
                 buf = layout.pack(

@@ -425,6 +425,10 @@ def test_traced_bods_decision_records_its_phases():
     assert by["bods_prepare"]["args"] == {"mutants": 8, "k": 40,
                                           "available": 32}
     assert by["bods_mutate"]["args"] == {"k": 40, "mutants": 8}
+    # The mutants of the base plan are the only rows repaired.
+    assert by["bods_acquire"]["args"] == {
+        "candidates": 32, "mutants": 8, "shards": 1, "select": "radix",
+        "repair_rows": 8}
     assert by["bods_observe"]["args"] == {"k": 40}
     # One packed transfer of the MAX_OBS ring of NUM_FEATURES features.
     layout = search._bods_layout(40, bods_mod.MAX_OBS, bods_mod.NUM_FEATURES,
@@ -648,3 +652,194 @@ def test_packed_bods_program_matches_unpacked_body(monkeypatch, use_base):
             _assert_bit_equal(g, w, (what, draw))
         np.testing.assert_array_equal(plan, np.asarray(got[0]))
         assert est == float(got[1])
+
+
+# ---- the sort-free candidate select ---------------------------------------
+
+def _top_k_scatter(keys, k):
+    """Reference: the mask of each row's ``lax.top_k`` indices."""
+    import jax
+    import jax.numpy as jnp
+
+    _, idx = jax.lax.top_k(keys, k)
+    rows = jnp.arange(keys.shape[0])[:, None]
+    return jnp.zeros(keys.shape, bool).at[rows, idx].set(True)
+
+
+def _select_keys(case, rng):
+    """(keys, k) of one ``_topk_mask`` case: float32 rows built so that the
+    k-th largest key is tied, infinite or signed zero where the case says."""
+    B, K = 6, 257
+    if case == "distinct":
+        return rng.normal(size=(B, K)), 40
+    if case == "heavy_ties":
+        return rng.integers(-3, 4, (B, K)), 100
+    if case == "ties_at_kth":
+        keys = np.repeat(np.arange(K // 4)[None, :], B, 0)
+        keys = np.concatenate([keys] * 4 + [keys[:, :1]], axis=1)
+        return rng.permuted(keys, axis=1), 3 * 4 + 2
+    if case == "minus_inf_rows":
+        keys = np.where(rng.random((B, K)) < 0.6, -np.inf,
+                        rng.normal(size=(B, K)))
+        keys[0] = -np.inf
+        return keys, 50
+    if case == "signed_zeros":
+        return rng.choice(np.array([0.0, -0.0, 1.0, -1.0]), (B, K)), 70
+    if case == "k_one":
+        return rng.integers(0, 3, (B, K)), 1
+    if case == "k_all_finite":
+        # Exactly k finite keys in every row, the rest unavailable.
+        keys = np.full((B, K), -np.inf)
+        for row in keys:
+            row[rng.choice(K, 60, replace=False)] = rng.integers(-2, 2, 60)
+        return keys, 60
+    if case == "extremes":
+        return rng.choice(np.array([np.inf, -np.inf, 3e38, -3e38, 1e-45,
+                                    -1e-45, 0.0, -0.0]), (B, K)), 128
+    raise ValueError(case)
+
+
+@pytest.mark.parametrize("case", [
+    "distinct", "heavy_ties", "ties_at_kth", "minus_inf_rows", "signed_zeros",
+    "k_one", "k_all_finite", "extremes"])
+def test_topk_mask_is_the_top_k_scatter_mask(case):
+    import jax
+
+    rng = np.random.default_rng(sum(map(ord, case)))
+    keys, k = _select_keys(case, rng)
+    keys = np.asarray(keys, np.float32)
+    got = np.asarray(jax.jit(search._topk_mask, static_argnums=1)(keys, k))
+    want = np.asarray(jax.jit(_top_k_scatter, static_argnums=1)(keys, k))
+    assert got.dtype == bool and (got.sum(axis=1) == k).all()
+    np.testing.assert_array_equal(got, want)
+
+
+def _reference_candidates(seed, ids, times, counts_c, avail, mutants,
+                          use_base, *, n_rand, n_mut, n_sel, local_search):
+    """The candidate generator as it stood before the radix select: per
+    candidate, a ``lax.top_k`` over its Gumbel keys and one over its repair
+    keys, scattered into masks, the repair kept on ids below ``n_mut``."""
+    import jax
+    import jax.numpy as jnp
+
+    K = times.shape[0]
+    t_norm = search._norm01_traced(times, avail)
+    c_norm = search._norm01_traced(counts_c, jnp.ones(K, bool))
+    base_key = jax.random.key(seed)
+
+    def one(cid):
+        k = jax.random.fold_in(base_key, cid)
+        kg, kw1, kw2, kr = jax.random.split(k, 4)
+        w_time = jax.random.uniform(kw1, (), minval=0.0, maxval=6.0)
+        w_fair = jax.random.uniform(kw2, (), minval=0.0, maxval=4.0)
+        logits = jnp.where(cid >= n_rand,
+                           -w_time * t_norm - w_fair * c_norm, 0.0)
+        g = jnp.where(avail, logits + jax.random.gumbel(kg, (K,)),
+                      -jnp.inf)
+        _, ti = jax.lax.top_k(g, n_sel)
+        plan = jnp.zeros((K,), bool).at[ti].set(True) & avail
+        if local_search:
+            mut = mutants[jnp.minimum(cid, n_mut - 1)]
+            rk = jnp.where(avail, (mut & avail) +
+                           jax.random.uniform(kr, (K,)), -jnp.inf)
+            _, ri = jax.lax.top_k(rk, n_sel)
+            rplan = jnp.zeros((K,), bool).at[ri].set(True) & avail
+            plan = jnp.where(use_base & (cid < n_mut), rplan, plan)
+        return plan
+
+    return jax.vmap(one)(ids)
+
+
+# (K, P, n_mut, n_sel, scarce): ``scarce`` leaves fewer available devices
+# than n_sel, so every row's select ends in a run of tied -inf keys.
+_IDENTITY_CASES = [(3000, 64, 16, 300, False), (3000, 64, 16, 2700, True),
+                   (3000, 64, 40, 300, False), (500, 64, 16, 50, False)]
+
+
+def _decide_with(candidates, key, buffers):
+    """A fresh ``jit_bods_acquire`` for ``key``, traced with ``candidates``
+    as its generator, run on each packed buffer."""
+    real = search._bods_candidates
+    search._bods_candidates = candidates
+    try:
+        fn = search._bods_fn.__wrapped__(*key)
+        return [fn(b) for b in buffers]
+    finally:
+        search._bods_candidates = real
+
+
+def check_decisions_match_reference(num_shards):
+    """``jit_bods_acquire`` over ``num_shards`` shards decides exactly as
+    the same program built on ``_reference_candidates``: plan, estimate and
+    EI bit for bit, with ``use_base`` true and false and local search on and
+    off. With two shards and n_mut 40 the mutant ids span both blocks."""
+    for K, P, n_mut, n_sel, scarce in _IDENTITY_CASES:
+        layout = search._bods_layout(K, 24, 6, n_mut)
+        buffers = []
+        for use_base in (True, False):
+            x = _bods_host_inputs(K, 24, 6, n_mut, 2**31 - 5, use_base,
+                                  draw=n_sel)
+            if scarce:
+                x["avail"] = np.arange(K) < n_sel - 40
+            buffers.append(_packed(layout, x))
+        for local_search in (True, False):
+            key = (P, n_mut, n_sel, True, local_search, num_shards, layout)
+            got = _decide_with(search._bods_candidates, key, buffers)
+            want = _decide_with(_reference_candidates, key, buffers)
+            for use_base, g3, w3 in zip((True, False), got, want):
+                for what, g, w in zip(("plan", "est", "ei"), g3, w3):
+                    _assert_bit_equal(g, w, (what, K, P, n_mut, n_sel,
+                                             use_base, local_search))
+
+
+def test_bods_decision_matches_top_k_reference():
+    check_decisions_match_reference(num_shards=1)
+
+
+@pytest.mark.parametrize("use_base", [True, False])
+def test_candidate_plans_match_top_k_reference(use_base):
+    """Every candidate plan, not only the chosen one, is the reference's."""
+    import jax
+    import jax.numpy as jnp
+
+    K, P, n_mut, n_sel = 3000, 64, 16, 300
+    x = _bods_host_inputs(K, 24, 6, n_mut, 12345, use_base)
+    staged = _staged_one_by_one(x)
+    args = (staged[0], jnp.arange(P, dtype=jnp.int32), staged[1], staged[2],
+            staged[4], staged[6], staged[7])
+    kw = dict(n_rand=P // 4, n_mut=n_mut, n_sel=n_sel, local_search=True)
+    got = jax.jit(lambda *a: search._bods_candidates(*a, **kw))(*args)
+    want = jax.jit(lambda *a: _reference_candidates(*a, **kw))(*args)
+    _assert_bit_equal(got, want, "plans")
+
+
+_SHARDED_IDENTITY = r"""
+import sys
+sys.path.insert(0, {tests!r})
+import jax
+assert jax.device_count() == 2, jax.device_count()
+import test_search
+test_search.check_decisions_match_reference(num_shards=2)
+print("SHARDED_IDENTITY_OK")
+"""
+
+
+def test_sharded_bods_decision_matches_top_k_reference():
+    """Two CPU host devices: the sharded program, whose second shard owns no
+    mutant ids (or, with n_mut 40, some), decides as its reference."""
+    import os
+    import subprocess
+    import sys
+
+    tests = os.path.dirname(os.path.abspath(__file__))
+    env = dict(os.environ)
+    env.update({
+        "PYTHONPATH": os.path.join(os.path.dirname(tests), "src"),
+        "XLA_FLAGS": "--xla_force_host_platform_device_count=2",
+        "JAX_PLATFORMS": "cpu",
+    })
+    out = subprocess.run(
+        [sys.executable, "-c", _SHARDED_IDENTITY.format(tests=tests)],
+        capture_output=True, text=True, env=env, timeout=600)
+    assert out.returncode == 0, out.stderr[-3000:]
+    assert "SHARDED_IDENTITY_OK" in out.stdout, out.stdout
