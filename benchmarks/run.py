@@ -1,14 +1,12 @@
 """Benchmark entrypoint: one section per paper table/figure.
 
   PYTHONPATH=src python -m benchmarks.run            # all tables
-  PYTHONPATH=src python -m benchmarks.run --only groups,roofline
+  PYTHONPATH=src python -m benchmarks.run --only groups,ablation
 
 Emits human tables + machine CSV lines (prefix "CSV,").
 Table map: groups -> paper Tables 1-2 (+Figs 3,5,6,7 trajectories as CSV),
-mj_vs_sj -> Table 5, ablation -> appendix fairness ablation,
-roofline -> EXPERIMENTS.md §Roofline source data,
-fleet -> BENCH_fleet.json (plan-scoring core perf, smoke-sized here;
-run benchmarks.bench_fleet directly for the full K=1e5 sweep).
+mj_vs_sj -> Table 5, ablation -> appendix fairness ablation.
+These are results, not speed: the speed benchmark is ``bench/run.py``.
 
 Every engine-backed section is spec-driven: each cell is a declarative
 ``repro.experiment.ExperimentSpec`` (see ``benchmarks/common.py``), so any
@@ -26,7 +24,7 @@ import time
 
 def main() -> None:
     ap = argparse.ArgumentParser()
-    ap.add_argument("--only", default="groups,mj_vs_sj,ablation,roofline,fleet")
+    ap.add_argument("--only", default="groups,mj_vs_sj,ablation")
     args = ap.parse_args()
     picks = set(args.only.split(","))
     t0 = time.time()
@@ -40,12 +38,6 @@ def main() -> None:
     if "ablation" in picks:
         from benchmarks import bench_ablation
         bench_ablation.main()
-    if "roofline" in picks:
-        from benchmarks import bench_roofline
-        bench_roofline.main()
-    if "fleet" in picks:
-        from benchmarks import bench_fleet
-        bench_fleet.main(["--smoke"])
 
     print(f"\nall benchmarks done in {time.time() - t0:.1f}s")
 

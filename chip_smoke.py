@@ -54,6 +54,8 @@ import traceback
 
 import numpy as np
 
+from bench.harness.clock import CompileClock
+
 ROOT = os.path.dirname(os.path.abspath(__file__))
 
 SCORE_RTOL = 1e-5      # numpy float64 reference vs the float32 device paths
@@ -73,27 +75,6 @@ def max_rel_err(out, ref) -> float:
     out, ref = np.asarray(out, np.float64), np.asarray(ref, np.float64)
     check(np.all(np.isfinite(out)), "non-finite output")
     return float(np.max(np.abs(out - ref) / np.maximum(np.abs(ref), 1e-12)))
-
-
-class CompileClock:
-    """Backend compile seconds and persistent-cache hits, from JAX's own
-    monitoring events (a cache hit reports its retrieval time instead)."""
-
-    def __init__(self):
-        import jax
-
-        self.compile_s = 0.0
-        self.cache_hits = 0
-        jax.monitoring.register_event_duration_secs_listener(self._duration)
-        jax.monitoring.register_event_listener(self._event)
-
-    def _duration(self, event, duration, **_):
-        if event == "/jax/core/compile/backend_compile_duration":
-            self.compile_s += duration
-
-    def _event(self, event, **_):
-        if event == "/jax/compilation_cache/cache_hits":
-            self.cache_hits += 1
 
 
 def record_decisions(scheduler) -> list:

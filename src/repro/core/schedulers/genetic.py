@@ -10,8 +10,7 @@ Two search backends (``search_backend``):
   (``repro.core.search.ga_search``) with vmapped tournament selection, the
   vectorized population repair, and the greedy plan seeding individual 0.
 - ``host``: the historical per-individual numpy loops, kept as the
-  behavioural reference (``benchmarks/bench_sched.py`` gates fused
-  against it).
+  behavioural reference (``tests/test_search.py`` holds fused to it).
 """
 
 from __future__ import annotations

@@ -15,10 +15,10 @@ Two search backends (``search_backend``):
   point of fusing). For an apples-to-apples comparison against ``host``,
   divide ``steps`` by ``chains`` and raise ``cooling`` to the
   ``chains``-th power so each short chain spans the same temperature
-  range — exactly what ``benchmarks/bench_sched.py`` does for its
-  matched-budget parity gate.
+  range — exactly what ``tests/test_search.py`` does for its
+  matched-budget parity tests.
 - ``host``: the historical sequential numpy loop, kept as the behavioural
-  reference (``benchmarks/bench_sched.py`` gates fused against it).
+  reference (``tests/test_search.py`` holds fused to it).
 """
 
 from __future__ import annotations

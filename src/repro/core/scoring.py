@@ -41,11 +41,11 @@ import numpy as np
 VALID_BACKENDS = ("auto", "numpy", "jax", "pallas")
 
 # Below these many elements the numpy path wins: jit dispatch + host->device
-# transfer costs more than the whole reduction. Calibrated per FORM from
-# BENCH_fleet.json (CPU): dense numpy/jax cross over between P*K = 2.6e5
-# (K=1e3, P=256: a tie) and 4.1e5 (jax clearly ahead); the index-form numpy
-# gather is still ahead at P*n_sel = 4.1e5 (K=1e4, P=4096) and loses by
-# 4.1e6 (K=1e5), so its threshold sits a factor of 4 higher.
+# transfer costs more than the whole reduction. These are CPU crossovers,
+# never measured on the chip. Per FORM: dense numpy/jax cross over between
+# P*K = 2.6e5 (K=1e3, P=256: a tie) and 4.1e5 (jax clearly ahead); the
+# index-form numpy gather is still ahead at P*n_sel = 4.1e5 (K=1e4, P=4096)
+# and loses by 4.1e6 (K=1e5), so its threshold sits a factor of 4 higher.
 AUTO_NUMPY_MAX_DENSE = 1 << 18
 AUTO_NUMPY_MAX_INDEX = 1 << 20
 # Back-compat alias (pre-calibration single threshold == the dense one).

@@ -40,7 +40,7 @@ per-experiment set is tiny: one compile per (steps, chains, n_sel)).
 Both fused population inits seed one slot with the greedy plan (the
 ``n_sel`` fastest available devices — a standard memetic warm start): at a
 matched evaluation budget the fused searchers then dominate the host path
-on chosen-plan cost, which ``benchmarks/bench_sched.py`` gates on.
+on chosen-plan cost, which ``tests/test_search.py`` checks.
 """
 
 from __future__ import annotations

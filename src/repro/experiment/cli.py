@@ -15,7 +15,7 @@ gym-trained scheduler policy from the zoo; train one with
 ``python -m repro.gym train``) and the search-backend axis
 (``--set search_backend=host|fused`` flips the SA/genetic/BODS plan search
 between the jitted on-device loops and the sequential numpy reference;
-see ``benchmarks/bench_sched.py``) and the observability axis (``--set
+see ``tests/test_search.py``) and the observability axis (``--set
 obs.trace_path=trace.json`` emits a Perfetto trace of the run, ``--set
 obs.metrics_path=m.jsonl`` / ``obs.audit_path=a.jsonl`` the round-metrics
 and scheduler-audit logs; inspect with ``python -m repro.monitoring

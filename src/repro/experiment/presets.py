@@ -194,7 +194,7 @@ def slo_overload(scheduler: str = "bods", num_devices: int = 40,
     breakers, bounded launch/aggregation retries, and the stalled-round
     watchdog. Deliberately leaves ``slo.decision_deadline_ms`` unset so the
     trajectory (including fired rungs) is bit-identical across crash/resume
-    — the overload-chaos CI arm depends on that."""
+    — the kill -9 resume tests depend on that."""
     spec = online_smoke(scheduler=scheduler, num_devices=num_devices,
                         horizon=horizon, interarrival=interarrival,
                         max_concurrent=max_concurrent, seed=seed)

@@ -438,8 +438,8 @@ class FLJobRuntime:
 
     Recompiles ``_local_train_batch`` for every distinct cohort size, gathers
     partitions through the host, and runs FedAvg eagerly; kept as the
-    reference ``benchmarks/bench_train.py`` measures the fused engine
-    against. FedAvg weights are the REAL per-device partition sizes
+    reference ``tests/test_train_runtime.py`` holds the fused engine
+    to. FedAvg weights are the REAL per-device partition sizes
     (``partition_sizes``; defaults to the fixed partition width, under which
     all weights are equal).
     """

@@ -13,8 +13,8 @@
   cost, degraded rounds, scheduler name).
 - ``session`` — ``ObsSpec`` (the spec's ``obs`` axis) + ``ObsSession``
   (declarative wiring: ``--set obs.trace_path=trace.json`` on any run).
-- ``report``  — per-phase wall-clock breakdowns, run diffs, and BENCH_*.json
-  regression checks (``python -m repro.monitoring report``).
+- ``report``  — per-phase wall-clock breakdowns and run diffs
+  (``python -m repro.monitoring report``).
 """
 
 from repro.monitoring.audit import SchedulerAudit

@@ -4,23 +4,16 @@
 wall-clock go": a per-phase breakdown table (count, total, p50/p99 per span
 kind), span coverage of engine wall-clock, the jit recompile count, rounds
 per second, and (given ``--metrics``) a per-job cost/fairness summary.
-``--diff other_trace.json`` prints per-phase p50 deltas between two runs;
-``--check-bench BENCH_obs.json [more BENCH_*.json ...]`` compares the
-trace's phase p50s against the benchmark baseline's recorded phases
-(tolerance-gated) and surfaces any ``gate.failures`` recorded inside the
-repo's BENCH_*.json artifacts — phase-level regression checking as a CLI
-one-liner.
+``--diff other_trace.json`` prints per-phase p50 deltas between two runs.
 
-All pure functions here (``phase_stats``, ``coverage``, ``diff_phases``,
-``check_bench``) are importable for programmatic use; the CLI lives in
+All pure functions here (``phase_stats``, ``coverage``, ``diff_phases``)
+are importable for programmatic use; the CLI lives in
 ``repro.monitoring.__main__``.
 """
 
 from __future__ import annotations
 
-import glob
 import json
-import os
 from typing import Dict, List, Optional, Tuple
 
 import numpy as np
@@ -192,49 +185,3 @@ def diff_phases(a: Dict[str, dict], b: Dict[str, dict]) -> Dict[str, dict]:
         }
     return out
 
-
-def check_bench(stats: Dict[str, dict], bench_paths: List[str],
-                tolerance: float = 0.5) -> List[str]:
-    """Phase-level regression check against BENCH_*.json artifacts.
-
-    Two sources of failure:
-    - a baseline file carrying a ``phases`` block (``BENCH_obs.json``):
-      any shared phase whose current p50 exceeds baseline * (1 + tolerance).
-      The ``engine_run`` root is skipped — it scales with workload length,
-      not per-round cost, so it never compares across runs of different
-      sizes (per-phase p50s are per-round quantities and do).
-    - any BENCH file whose ``gate.failures`` list is non-empty (the repo's
-      benchmark gates record their own verdicts there).
-
-    ``bench_paths`` entries may be files, directories (scanned for
-    ``BENCH_*.json``), or globs. Returns human-readable failure strings
-    (empty = clean).
-    """
-    paths: List[str] = []
-    for p in bench_paths:
-        if os.path.isdir(p):
-            paths.extend(sorted(glob.glob(os.path.join(p, "BENCH_*.json"))))
-        else:
-            paths.extend(sorted(glob.glob(p)) or [p])
-    failures = []
-    for path in paths:
-        try:
-            with open(path) as f:
-                bench = json.load(f)
-        except (OSError, json.JSONDecodeError) as e:
-            failures.append(f"{path}: unreadable ({e})")
-            continue
-        base = bench.get("phases")
-        if isinstance(base, dict):
-            for name in sorted(set(base) & set(stats) - {"engine_run"}):
-                b50 = float(base[name].get("p50_ms", 0.0))
-                cur = stats[name]["p50_ms"]
-                if b50 > 0 and cur > b50 * (1.0 + tolerance):
-                    failures.append(
-                        f"{path}: phase {name!r} p50 {cur:.3f}ms exceeds "
-                        f"baseline {b50:.3f}ms by more than "
-                        f"{tolerance * 100:.0f}%")
-        gate = bench.get("gate", {})
-        for msg in gate.get("failures", []) or []:
-            failures.append(f"{path}: recorded gate failure: {msg}")
-    return failures

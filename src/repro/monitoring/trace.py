@@ -30,10 +30,9 @@ Disabled (the default), ``span()`` returns a shared no-op context manager
 without allocating anything (no annotation either), and
 ``counter``/``instant`` return immediately: no RNG is touched, no arrays
 are built, so traced and untraced runs execute the SAME computation
-(``benchmarks/bench_obs.py`` gates enabled-vs-disabled engine records
-bitwise and overhead <= 3%). Span arguments are evaluated before the
-check, so a count that costs work to compute goes through ``annotate``
-behind ``enabled()``.
+(``tests/test_obs.py`` checks enabled-vs-disabled engine records
+bitwise). Span arguments are evaluated before the check, so a count that
+costs work to compute goes through ``annotate`` behind ``enabled()``.
 
 Ownership: instrumented library code uses the module-global tracer via
 ``span``/``counter``/``instant``; ``repro.monitoring.session.ObsSession``

@@ -233,7 +233,7 @@ class DecisionGovernor:
         self.rung_counts: Dict[str, int] = {r: 0 for r in RUNGS}
         self.deadline_misses = 0
         self._forced = 0          # latency-forced degradations (probe clock)
-        # Bench hook: keep (ctx, chosen idx, rung, est) per decision.
+        # Test hook: keep (ctx, chosen idx, rung, est) per decision.
         self.keep_decisions = False
         self.decision_log: List[dict] = []
 

@@ -19,8 +19,8 @@ every live job under the post-arrival world state) runs in one of two modes:
   whose world is unchanged (``pool.version`` + round index as the cache
   key). Churn invalidates exactly the affected entries.
 - ``full``        — re-run a cold scheduler's complete plan SEARCH for
-  every live job (the ablation baseline the incremental path is benched
-  against; ``benchmarks/bench_serve.py`` gates the speedup).
+  every live job (the ablation baseline the incremental path is compared
+  against; ``tests/test_serve.py`` checks the two execute identically).
 
 Both modes are ADVISORY: executed plans always come from the live
 scheduler inside the engine, so the realized trajectory is identical across

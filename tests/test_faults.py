@@ -188,6 +188,18 @@ def test_degraded_runs_stay_reproducible():
         np.testing.assert_array_equal(a.corrupt_ids, b.corrupt_ids)
 
 
+def test_fault_injection_preset_injects_faults_and_stays_finite():
+    from repro.experiment.presets import get_preset
+
+    spec = get_preset("fault-injection", scheduler="random", num_devices=60)
+    res = spec.replace(jobs=tuple(dataclasses.replace(j, max_rounds=8)
+                                  for j in spec.jobs)).run()
+    assert sum(len(r.dropped) for r in res.records) > 0
+    assert sum(len(r.corrupt_ids) for r in res.records) > 0
+    assert all(np.isfinite([r.accuracy, r.loss, r.round_time]).all()
+               for r in res.records)
+
+
 # ---- robust aggregation (fused runtime) ----------------------------------
 
 def _tiny_fl_setup(num_jobs=1, num_dev=12, seed=0):

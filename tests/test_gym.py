@@ -188,6 +188,22 @@ def test_train_rlds_runs_and_changes_params():
     assert np.isfinite(ev["mean_cost"])
 
 
+def test_trained_policy_no_worse_than_untrained():
+    """A gym-trained RLDS policy must not score a higher mean cost than
+    the untrained (random-init, no-pretrain) policy it replaces, on paired
+    held-out scenarios (same eval seed, greedy top-k)."""
+    from repro.core.schedulers.rlds import init_policy
+
+    stages = default_stages("default", num_devices=(24,), num_jobs=2)
+    params, _ = train_rlds(
+        stages, TrainConfig(num_envs=8, rollout_len=8, iters=20), seed=0)
+    cfg, scen = stages[0]
+    untrained = init_policy(jax.random.PRNGKey(99))
+    ev_t = evaluate(cfg, scen, params, seed=7, episodes=16, steps=32)
+    ev_u = evaluate(cfg, scen, untrained, seed=7, episodes=16, steps=32)
+    assert ev_t["mean_cost"] <= ev_u["mean_cost"], (ev_t, ev_u)
+
+
 # ---- policy zoo ----------------------------------------------------------
 
 def _pool_cm(K=24, M=2, n_sel=3, seed=0):

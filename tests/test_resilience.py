@@ -131,6 +131,18 @@ def test_inert_slo_axis_is_bit_identical():
     assert record_tuples(recs_off) == record_tuples(recs_inert)
 
 
+def test_idle_governor_keeps_the_plans():
+    """A governor that never degrades (queue bound out of reach, no
+    deadline) changes nothing but its own ``rung`` annotation."""
+    base = small_quickstart(max_rounds=8)
+    recs_off = base.build().run().records
+    recs_idle = base.replace(
+        slo={"max_queue_depth": 1_000_000}).build().run().records
+    assert {r.rung for r in recs_idle} == {"full"}
+    assert record_tuples(recs_off) == record_tuples(
+        [dataclasses.replace(r, rung=None) for r in recs_idle])
+
+
 # ---------------------------------------------------------------------------
 # governor: rung selection, repair, decide
 # ---------------------------------------------------------------------------
@@ -377,6 +389,41 @@ def _overload_spec():
     return get_preset("slo-overload", horizon=5_000.0, num_devices=30)
 
 
+@pytest.fixture(scope="module")
+def overload_run():
+    svc = SchedulerService(_overload_spec())
+    svc.engine.governor.keep_decisions = True
+    return svc, svc.run()
+
+
+def test_degraded_plans_cost_at_most_twice_the_full_search(overload_run):
+    """Each degraded rung's plan, scored on the context it was chosen
+    for, costs at most 2x the plan the full search picks there."""
+    svc, _ = overload_run
+    cost_model, scheduler = svc.engine.cost_model, svc.engine.scheduler
+
+    def cost(ctx, idx):
+        return float(np.asarray(cost_model.cost_indices(
+            ctx.expected_times, ctx.counts, idx[None]))[0])
+
+    scored = 0
+    for d in svc.engine.governor.decision_log:
+        if d["rung"] == "full":
+            continue
+        ctx = d["ctx"]
+        full = cost(ctx, np.flatnonzero(scheduler.schedule(ctx)))
+        if full > 0:
+            assert cost(ctx, d["idx"]) <= 2.0 * full, d["rung"]
+            scored += 1
+    assert scored > 0
+
+
+def test_overload_sheds_at_most_half_the_arrivals(overload_run):
+    _, report = overload_run
+    shed = report.resilience["shed_arrivals"]
+    assert 0 < shed <= report.arrivals / 2, (shed, report.arrivals)
+
+
 def _deterministic_summary(svc):
     s = dict(svc.resilience_summary())
     s.pop("rung_latency_ms", None)   # wall clock: not replayable
@@ -405,8 +452,8 @@ def test_degrading_service_survives_kill9_bit_identically(tmp_path):
     assert _deterministic_summary(resumed) == ref_summary
 
 
-def test_service_report_carries_resilience_block():
-    report = SchedulerService(_overload_spec()).run()
+def test_service_report_carries_resilience_block(overload_run):
+    _, report = overload_run
     res = report.resilience
     assert res is not None
     assert sum(res["rung_counts"].values()) == res["decisions"]

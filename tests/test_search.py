@@ -133,7 +133,7 @@ def test_repair_plans_jax_matches_contract():
         assert np.array_equal(fixed, valid)
 
 
-# ---- per-form auto dispatch (calibrated from BENCH_fleet.json) ------------
+# ---- per-form auto dispatch (CPU crossovers, see core/scoring.py) ----------
 
 def test_auto_dispatch_per_form_thresholds():
     # Dense: numpy through the K=1e3/P=256 tie (2.56e5), jax by 4.1e5.
@@ -269,6 +269,23 @@ def test_bods_fused_comparable_and_beats_random():
     rand = _mean_chosen_cost("random", {}, seeds)
     assert fused <= host * 1.15, (fused, host)
     assert fused < rand, (fused, rand)
+
+
+@pytest.mark.parametrize("name,host_kw,fused_kw,tol", [
+    ("sa", dict(steps=200), dict(steps=25, chains=8, cooling=0.97 ** 8),
+     1.005),
+    ("genetic", {}, {}, 1.005),
+    ("bods", {}, {}, 1.10),
+])
+def test_fused_no_worse_than_host_at_k1000(name, host_kw, fused_kw, tol):
+    """The same parity at K=1000 (n_sel=10, 20% of the pool busy), with
+    BODS held to 10%: its EI-driven choice is statistical, not monotone."""
+    sizes = dict(seeds=range(3), K=1000, n_sel=10, reps=10)
+    host = _mean_chosen_cost(name, dict(search_backend="host", **host_kw),
+                             **sizes)
+    fused = _mean_chosen_cost(name, dict(search_backend="fused", **fused_kw),
+                              **sizes)
+    assert fused <= host * tol, (fused, host)
 
 
 # ---- SA small fixes --------------------------------------------------------

@@ -154,18 +154,37 @@ def test_service_readmission_uses_saved_state():
     assert len(jobs) == len(set(jobs))
 
 
-def test_incremental_and_full_rescoring_execute_identically():
+@pytest.fixture(scope="module")
+def rescore_runs():
+    """One BODS service per rescoring mode, over the same traffic trace."""
     spec = small_spec(scheduler="bods", horizon=4_000.0)
     probe = SchedulerService(spec)
     trace = trace_from_spec(spec.arrivals, len(probe.templates),
                             probe.engine.pool.num_devices)
     runs = {}
     for mode in ("incremental", "full"):
-        svc = SchedulerService(spec, rescore_mode=mode)
-        svc.run(trace)
-        runs[mode] = [(r.job, r.round_idx, r.cost, tuple(r.device_ids))
+        runs[mode] = SchedulerService(spec, rescore_mode=mode)
+        runs[mode].run(trace)
+    return runs
+
+
+def test_incremental_and_full_rescoring_execute_identically(rescore_runs):
+    records = {mode: [(r.job, r.round_idx, r.cost, tuple(r.device_ids))
                       for r in svc.engine.records]
-    assert runs["incremental"] == runs["full"]
+               for mode, svc in rescore_runs.items()}
+    assert records["incremental"] == records["full"]
+
+
+def test_incremental_and_full_advisory_costs_agree(rescore_runs):
+    """Incremental rescoring scores the live plan, full rescoring searches
+    a fresh one: their mean advisory costs agree within 50%."""
+    means = {}
+    for mode, svc in rescore_runs.items():
+        advisory = [c for c in svc.rescore_costs if c > 0]
+        assert advisory, mode
+        means[mode] = float(np.mean(advisory))
+    rel = abs(means["incremental"] - means["full"]) / means["full"]
+    assert rel <= 0.5, means
 
 
 def test_service_requires_arrivals_axis():

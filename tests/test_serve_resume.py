@@ -6,15 +6,22 @@ run — records, fairness counts, tenant metrics, rescore costs, and the
 summary, across schedulers (including the stateful BODS ring)."""
 
 import dataclasses
+import json
+import os
+import subprocess
+import sys
 
 import numpy as np
 import pytest
 
 from repro.checkpoint import committed_steps
 from repro.experiment.presets import get_preset
+from repro.experiment.spec import _record_to_dict
 from repro.faults import FaultSpec
 from repro.serve.metrics import ServiceMetrics
 from repro.serve.service import SchedulerService, SimulatedCrash
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def service_spec(scheduler="bods", with_faults=True, num_devices=40):
@@ -147,3 +154,42 @@ def test_crash_before_first_checkpoint_restarts_clean(tmp_path):
     assert committed_steps(ck) == []
     with pytest.raises(FileNotFoundError):
         SchedulerService.resume(ck)
+
+
+def _serve_cli(args, cwd):
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.pathsep.join(
+        p for p in (os.path.join(REPO, "src"), env.get("PYTHONPATH")) if p)
+    return subprocess.run([sys.executable, "-m", "repro.serve", *args],
+                          cwd=cwd, env=env, capture_output=True, text=True)
+
+
+@pytest.mark.parametrize("overload", [False, True],
+                         ids=["faults", "slo-overload"])
+def test_cli_kill9_and_resume_bit_identical(overload, tmp_path):
+    """``python -m repro.serve --crash-after`` dies by ``os._exit(137)``;
+    ``--resume`` then finishes the run with records identical to an
+    uninterrupted one, with faults injected and every metric finite."""
+    spec = (get_preset("slo-overload", horizon=5_000.0, num_devices=30)
+            if overload else service_spec("bods"))
+    ref, _ = run_reference(spec)
+    ref_records = json.loads(json.dumps(
+        [_record_to_dict(r) for r in ref.engine.records]))
+    assert sum(len(r["dropped"]) for r in ref_records) > 0
+    assert sum(len(r["corrupt_ids"]) for r in ref_records) > 0
+    assert all(np.isfinite([r["accuracy"], r["loss"], r["round_time"]]).all()
+               for r in ref_records)
+    if overload:
+        assert any(r["rung"] != "full" for r in ref_records)
+
+    spec_path, ck = tmp_path / "spec.json", str(tmp_path / "ck")
+    spec.save(str(spec_path))
+    crash = _serve_cli(["--spec", str(spec_path), "--checkpoint-dir", ck,
+                        "--checkpoint-every", "3", "--crash-after", "7"],
+                       tmp_path)
+    assert crash.returncode == 137, crash.stderr[-2000:]
+    out = tmp_path / "records.json"
+    resume = _serve_cli(["--resume", ck, "--records-out", str(out)],
+                        tmp_path)
+    assert resume.returncode == 0, resume.stderr[-2000:]
+    assert json.loads(out.read_text()) == ref_records
